@@ -59,6 +59,8 @@ def main() -> None:
                          "BENCH_trajectory.json in --out-dir — the "
                          "tracked perf trajectory across PRs")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     def want(name):

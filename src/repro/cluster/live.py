@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import time
+import traceback
 from typing import Callable, Iterable, List, Optional, Sequence, Union
 
 import jax
@@ -351,8 +352,7 @@ class EdgeCluster:
                 try:
                     done += e.step()
                 except Exception as exc:  # noqa: BLE001 — quarantine all
-                    self.fault_stats["quarantined"] += 1
-                    done += self._crash(i, f"quarantined: {exc!r}")
+                    done += self._quarantine(i, exc)
             return done
         for i, e in enumerate(self.engines):
             if not e.available:
@@ -360,17 +360,22 @@ class EdgeCluster:
             try:
                 e.dispatch()
             except Exception as exc:   # noqa: BLE001 — quarantine anything
-                self.fault_stats["quarantined"] += 1
-                done += self._crash(i, f"quarantined: {exc!r}")
+                done += self._quarantine(i, exc)
         for i, e in enumerate(self.engines):
             if not e.available:         # crashed during dispatch: pending
                 continue                # already dropped by fail()
             try:
                 done += e.collect()
             except Exception as exc:   # noqa: BLE001 — quarantine anything
-                self.fault_stats["quarantined"] += 1
-                done += self._crash(i, f"quarantined: {exc!r}")
+                done += self._quarantine(i, exc)
         return done
+
+    def _quarantine(self, i: int, exc: Exception) -> List[Request]:
+        """Take engine ``i`` out after it raised: the traceback goes to
+        stderr so the cause stays visible while the cluster serves on."""
+        traceback.print_exc()
+        self.fault_stats["quarantined"] += 1
+        return self._crash(i, f"quarantined: {exc!r}")
 
     @property
     def busy(self) -> bool:

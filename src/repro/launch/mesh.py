@@ -5,17 +5,30 @@ Multi-pod  : (2, 16, 16) -> ("pod", "data", "model"), 512 chips
 
 Functions (not module constants) so importing never touches jax device
 state — the dry-run must set XLA_FLAGS before the first jax call.
+
+Every mesh is built by :func:`make_mesh` with ``Auto`` axes: the model
+code places activations with ``with_sharding_constraint`` and lets GSPMD
+propagate the rest, which ``Explicit`` axes (``jax.make_mesh``'s default
+since JAX 0.7) reject.
 """
 from __future__ import annotations
 
 import math
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12        # per chip, FLOP/s
 HBM_BW = 819e9                  # per chip, bytes/s
 ICI_BW = 50e9                   # per link, bytes/s
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (see module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None, axes=None):
@@ -40,12 +53,12 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None, axes=None):
             f"{need} chips) does not factor into this runtime's "
             f"{have} device(s); run on a {need}-chip slice or use "
             f"make_smoke_mesh() for single-device smoke tests")
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
 
 
 def mesh_chips(mesh) -> int:

@@ -1,4 +1,4 @@
-"""Serving launcher: a DEdgeAI-style edge cluster on reduced models.
+"""Serving launcher: a DEdgeAI-style edge cluster of model replicas.
 
 Replays a Poisson arrival trace through N continuous-batching engines,
 with a pluggable scheduler placing each request:
@@ -9,6 +9,13 @@ with a pluggable scheduler placing each request:
 ``--scheduler lad-ts`` first trains the paper policy in the
 ``repro.core.env`` simulator (matching the engine count), then serves
 with it — the closed loop of paper Fig. 10.
+
+Engines serve the ``reduced()`` CPU-sized variant of ``--arch`` at
+heterogeneous depths unless ``--full-scale`` asks for the published
+config as it stands (its own depth and widths, bf16, scan over layers:
+what a TPU serves).  An engine that raises is quarantined so the rest
+of the cluster keeps serving, but without ``--chaos`` any quarantine
+makes the launcher exit non-zero.
 
 ``--qos`` switches on the heterogeneous-QoS workload layer
 (``repro.workload``): the trace mixes interactive / standard / batch
@@ -40,6 +47,7 @@ from repro.core.diffusion import DiffusionPolicyConfig
 from repro.core.env import EnvParams
 from repro.core.trainer import LEARNED, train_method
 from repro.faults import FaultInjector, FaultParams, FaultSpec, RetryPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.builders import build_engines, warmup
 from repro.workload import DEFAULT_MIX
 
@@ -102,19 +110,26 @@ def main():
     ap.add_argument("--prefix-frac", type=float, default=0.75)
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable the paged engines' prefix cache")
+    ap.add_argument("--full-scale", action="store_true",
+                    help="serve the published config (its own depth, "
+                         "widths and dtype) instead of the reduced one")
     ap.add_argument("--sample", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     max_tokens = (max(args.tokens,
                       *(c.z_range[1] for c, _ in DEFAULT_MIX))
                   if args.qos else args.tokens)
+    cfg = get_config(args.arch)
+    if not args.full_scale:
+        cfg = reduced(cfg)
     engines = build_engines(args.arch, args.edges,
-                            args.prompt_len + max_tokens
-                            + reduced(get_config(args.arch)).vision_patches,
+                            args.prompt_len + max_tokens + cfg.vision_patches,
                             kv_slots=args.kv_slots, sample=args.sample,
                             prefix_cache=(False if args.no_prefix_cache
-                                          else None))
+                                          else None),
+                            full_scale=args.full_scale)
     cfg0 = engines[0].cfg
     vocab = cfg0.vocab_size
     warmup(engines, args.prompt_len)       # compile before timed serving
@@ -183,6 +198,10 @@ def main():
                   f"p95={cs['p95_s']*1e3:.1f}ms "
                   f"miss={cs['deadline_miss_rate']:.2f}")
     print(line)
+    quarantined = cluster.fault_stats["quarantined"]
+    if quarantined and not args.chaos:
+        raise SystemExit(f"[serve] {quarantined} engine(s) quarantined "
+                         f"after raising; see the failed requests above")
 
 
 if __name__ == "__main__":

@@ -175,6 +175,17 @@ def param_shardings(mesh: Mesh, params) -> object:
 # ---------------------------------------------------------------------------
 
 
+def _fits(axes, n: int, axis_sizes: Optional[Dict[str, int]]) -> bool:
+    """Whether mesh ``axes`` (one name or a tuple) split a dim of ``n``
+    evenly — NamedSharding on concrete arrays forbids uneven partitions."""
+    if axis_sizes is None:
+        return True
+    size = 1
+    for a in (axes if isinstance(axes, tuple) else (axes,)):
+        size *= axis_sizes.get(a, 1)
+    return size <= 1 or n % size == 0
+
+
 def _state_leaf_spec(path: Tuple[str, ...], shape: Tuple[int, ...],
                      batch_axes,
                      axis_sizes: Optional[Dict[str, int]] = None) -> P:
@@ -187,17 +198,18 @@ def _state_leaf_spec(path: Tuple[str, ...], shape: Tuple[int, ...],
         return P(*spec)
 
     def put(dim: int, axes) -> None:
-        # divisibility guard: NamedSharding on concrete arrays forbids
-        # uneven partitions, so a dim the mesh axis doesn't divide falls
-        # back to replication (e.g. a 6-lane dense pool on 4-way 'data')
-        if axis_sizes is not None:
-            size = 1
-            for a in (axes if isinstance(axes, tuple) else (axes,)):
-                size *= axis_sizes.get(a, 1)
-            if size > 1 and shape[dim] % size != 0:
-                return
-        spec[dim] = axes
+        # divisibility guard: a dim the mesh axes don't divide falls back
+        # to replication (e.g. a 6-lane dense pool on 4-way 'data')
+        if _fits(axes, shape[dim], axis_sizes):
+            spec[dim] = axes
 
+    if name in ("k_pages", "v_pages") and nd == 4:
+        # paged pool (pages, KV, page_size, hd): block tables hold global
+        # page ids, so every shard keeps all pages (replicated over the
+        # batch axes) and KV heads split over 'model' — see
+        # paged_decode_pspecs
+        put(off + 1, "model")
+        return P(*spec)
     put(off, batch_axes)  # leading real dim is always batch
     if name in ("k", "v", "k_scale", "v_scale") and nd == 4:
         put(off + 2, "model")         # KV cache: shard the sequence dim
@@ -225,6 +237,28 @@ def state_pspecs(states, mesh: Optional[Mesh] = None,
         specs.append(_state_leaf_spec(path, leaf.shape, batch_axes,
                                       axis_sizes))
     return jax.tree_util.tree_unflatten(flat[1], specs)
+
+
+def paged_decode_pspecs(ctx: ShardingContext, lanes: int,
+                        pool_shape: Tuple[int, ...]) -> Tuple[P, P, P, P]:
+    """``shard_map`` specs of one paged decode kernel call under ``ctx``:
+    (page pool, query/output heads, block tables, lengths).
+
+    The pool spec is the one ``state_pspecs`` gives a layer's
+    ``k_pages``/``v_pages``: every page on every shard, KV heads over
+    'model' where it divides them.  Query heads follow their KV heads
+    (head h reads KV head h // G, so contiguous splits line up).  Lanes
+    split over the batch axes where those divide ``lanes``; the pool is
+    replicated over them, so on a mesh with data > 1 every data replica
+    holds the whole pool."""
+    sizes = dict(ctx.mesh.shape)
+    pool = _state_leaf_spec(("k_pages",), tuple(pool_shape), None, sizes)
+    rows = ctx.rules.get("batch")
+    names = rows if isinstance(rows, tuple) else (rows,)
+    if rows is None or not set(names) <= set(ctx.mesh.axis_names) \
+            or not _fits(rows, lanes, sizes):
+        rows = None
+    return pool, P(rows, pool[1], None), P(rows, None), P(rows)
 
 
 def state_shardings(mesh: Mesh, states) -> object:

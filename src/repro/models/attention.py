@@ -329,6 +329,28 @@ def paged_prefill_chunk(p, cfg, blk, x, cache, block_table, start
     return out, {"k_pages": k_pages, "v_pages": v_pages}
 
 
+def paged_attention(q, k_pages, v_pages, block_tables, lengths
+                    ) -> jnp.ndarray:
+    """The paged flash-decode kernel call (``kernels.ops``).
+
+    Under an active ShardingContext it runs inside ``shard_map``: GSPMD
+    cannot partition a Mosaic kernel.  Each shard sees the page pool as
+    ``state_pspecs`` places it and its own KV heads and lanes
+    (``sharding.paged_decode_pspecs``)."""
+    from repro.kernels import ops as kernel_ops
+
+    kernel = kernel_ops.paged_flash_decode
+    ctx = sharding.current()
+    if ctx is None:
+        return kernel(q, k_pages, v_pages, block_tables, lengths)
+    pool, heads, rows, lens = sharding.paged_decode_pspecs(
+        ctx, q.shape[0], k_pages.shape)
+    return jax.shard_map(
+        kernel, mesh=ctx.mesh,
+        in_specs=(heads, pool, pool, rows, lens), out_specs=heads,
+        check_vma=False)(q, k_pages, v_pages, block_tables, lengths)
+
+
 def paged_decode(p, cfg, blk, x, cache, block_tables, lengths
                  ) -> Tuple[jnp.ndarray, dict]:
     """One-token decode over the shared page pool.
@@ -340,8 +362,6 @@ def paged_decode(p, cfg, blk, x, cache, block_tables, lengths
     (all page 0) and length 0 — their writes and reads hit the reserved
     null page and their outputs are discarded by the engine.
     """
-    from repro.kernels import ops as kernel_ops
-
     B = x.shape[0]
     hd = cfg.head_dim
     q, k, v = _project_qkv(p, cfg, x, lengths[:, None], blk.use_rope)
@@ -353,8 +373,8 @@ def paged_decode(p, cfg, blk, x, cache, block_tables, lengths
         k[:, 0].astype(dtype))
     v_pages = cache["v_pages"].at[page, :, slot].set(
         v[:, 0].astype(dtype))
-    out = kernel_ops.paged_flash_decode(q[:, 0], k_pages, v_pages,
-                                        block_tables, lengths + 1)
+    out = paged_attention(q[:, 0], k_pages, v_pages, block_tables,
+                          lengths + 1)
     out = out.reshape(B, 1, cfg.num_heads * hd).astype(x.dtype)
     out = out @ p["wo"]
     return out, {"k_pages": k_pages, "v_pages": v_pages}

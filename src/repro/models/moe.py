@@ -34,15 +34,6 @@ from jax.sharding import PartitionSpec as P
 from repro.launch import sharding
 from repro.models.layers import dense_init
 
-# shard_map moved to the jax namespace (and check_rep -> check_vma) in
-# newer releases; support both so the multidevice paths run everywhere.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:                                              # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 def init_moe(key, cfg, dtype) -> dict:
     m = cfg.moe
@@ -276,10 +267,10 @@ def _apply_moe_sharded(p, cfg, xf, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
         in_specs = in_specs + (P(None),)
         args.append(jnp.zeros((M, r, 1, 1), xf.dtype))  # unused placeholder
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body if gated else (lambda x, rw, a, b, c: body(x, rw, a, b, None)),
         mesh=mesh, in_specs=in_specs,
-        out_specs=(tok_spec, P()), **_SHARD_MAP_KW)
+        out_specs=(tok_spec, P()), check_vma=False)
     out, aux = fn(*args)
     return out, aux[()] if aux.ndim else aux
 
@@ -383,10 +374,10 @@ def _apply_moe_stationary(p, cfg, xf, ctx) -> Tuple[jnp.ndarray,
                 P("model", "data") if gated else P(None))
     args = [xf, p["router"], wu, wd,
             wg if gated else jnp.zeros((M, D, 1, 1, 1), xf.dtype)]
-    fn = _shard_map(
+    fn = jax.shard_map(
         body if gated else (lambda x, rw, a, b, c: body(x, rw, a, b, None)),
         mesh=mesh, in_specs=in_specs, out_specs=(tok_spec, P()),
-        **_SHARD_MAP_KW)
+        check_vma=False)
     out, aux = fn(*args)
     return out, aux[()] if aux.ndim else aux
 

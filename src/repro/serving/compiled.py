@@ -24,10 +24,14 @@ input is never read again.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
+from repro.launch.sharding import param_shardings, state_shardings
+from repro.models.transformer import abstract_params, init_params
 from repro.train.steps import (make_decode_step, make_paged_decode_step,
                                make_paged_prefill_step, make_prefill_step)
 
@@ -53,6 +57,36 @@ def cache_info() -> Dict[str, Any]:
 def clear_cache() -> None:
     _CACHE.clear()
     _STATS["hits"] = _STATS["misses"] = 0
+
+
+def param_init(cfg, mesh=None) -> Callable:
+    """Jitted ``init_params(key, cfg)`` whose every leaf is built directly
+    into its ``param_shardings`` placement on ``mesh`` (the default
+    device when None): a model too big for one chip never exists whole
+    on chip 0 (nor do the per-layer copies the scan stacking makes)."""
+    def build():
+        shardings = (param_shardings(mesh, abstract_params(cfg))
+                     if mesh is not None else None)
+        return jax.jit(functools.partial(init_params, cfg=cfg),
+                       out_shardings=shardings)
+
+    return _get(("param_init", cfg, mesh), build)
+
+
+def state_zeros(abstract, mesh) -> Callable:
+    """Jitted zero KV / recurrent state for a ShapeDtypeStruct tree, built
+    directly into its ``state_shardings`` placement on ``mesh``."""
+    leaves, tree = jax.tree_util.tree_flatten(abstract)
+    key = tuple((a.shape, a.dtype) for a in leaves)
+
+    def build():
+        def zeros():
+            return jax.tree_util.tree_unflatten(
+                tree, [jnp.zeros(shape, dtype) for shape, dtype in key])
+
+        return jax.jit(zeros, out_shardings=state_shardings(mesh, abstract))
+
+    return _get(("state_zeros", tree, key, mesh), build)
 
 
 # ---------------------------------------------------------------------------

@@ -45,10 +45,13 @@ are donated (in-place pool update, no per-round copy).
 **Sharded big-model engines.**  Pass ``mesh=`` (e.g.
 ``launch.mesh.make_smoke_mesh()`` on CPU CI or ``make_production_mesh()``
 on real devices) and the engine places params via
-``launch.sharding.param_shardings`` and its KV pool / recurrent states
-via ``state_pspecs`` on that mesh, running every step inside the
-corresponding :class:`~repro.launch.sharding.ShardingContext` — this is
-how ``mixtral_8x22b`` / ``dbrx_132b`` scale configs serve.
+``launch.sharding.param_shardings`` (a no-op for params the builders
+already generated there) and builds its KV pool / recurrent states
+directly into their ``state_pspecs`` placement on that mesh, running
+every step inside the corresponding
+:class:`~repro.launch.sharding.ShardingContext` — this is how
+``starcoder2-7b`` on four chips and the ``mixtral_8x22b`` /
+``dbrx_132b`` scale configs serve.
 
 Per-request latency is MEASURED, not modelled: the Request lifecycle
 timestamps (queue / prefill / decode) decompose the serving-side terms of
@@ -846,9 +849,9 @@ class ServeEngine:
     def _ensure_paged_states(self) -> None:
         if self._paged_states is None:
             from repro.models.transformer import init_paged_states
-            states = init_paged_states(self.cfg, self.num_pages,
-                                       self.page_size)
-            self._paged_states = self._place_states(states)
+            self._paged_states = self._zeros(init_paged_states(
+                self.cfg, self.num_pages, self.page_size,
+                make=jax.ShapeDtypeStruct))
 
     def _ensure_pool(self, st):
         """Lazily build the slot pool from the structure of the first
@@ -857,17 +860,18 @@ class ServeEngine:
         if self._pool_states is not None:
             return
         slots = self.kv_slots
-        pool = jax.tree_util.tree_map(
-            lambda leaf: jnp.zeros((slots,) + leaf.shape, leaf.dtype), st)
-        self._pool_states = self._place_states(pool)
+        self._pool_states = self._zeros(jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct((slots,) + leaf.shape,
+                                              leaf.dtype), st))
 
-    def _place_states(self, states):
-        """Shard KV / recurrent state onto the engine's mesh (identity
-        when unsharded); divisibility-guarded per leaf."""
+    def _zeros(self, abstract):
+        """Zero KV / recurrent state for a ShapeDtypeStruct tree, built
+        directly into its mesh placement (divisibility-guarded per leaf
+        by ``state_shardings``) — never whole on one device first."""
         if self.mesh is None:
-            return states
-        shardings = shlib.state_shardings(self.mesh, states)
-        return jax.device_put(states, shardings)
+            return jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype), abstract)
+        return compiled.state_zeros(abstract, self.mesh)()
 
 
 def serve_batch(engines: List[ServeEngine], assignments: List[int],

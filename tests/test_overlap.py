@@ -1,6 +1,10 @@
 """Fleet-scale serving fast path: overlapped dispatch/collect parity,
 the shared compiled-step cache, and sharded big-model engines."""
 import dataclasses
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import numpy as np
@@ -232,3 +236,91 @@ def test_sharded_engine_matches_unsharded_tokens():
     ra = plain.generate(prompt, 4)
     rb = sharded.generate(prompt, 4)
     assert np.array_equal(np.asarray(ra.tokens), np.asarray(rb.tokens))
+
+
+def test_param_init_builds_into_shardings_equal_to_init_params():
+    """Params generated directly into their smoke-mesh shardings hold
+    exactly the values ``init_params`` gives for the same key.  (Both
+    jitted: XLA folds the init's constant scales differently from eager
+    op-by-op dispatch, a last-bit difference unrelated to placement.)"""
+    cfg = dataclasses.replace(reduced(get_config("qwen2-1.5b")),
+                              num_layers=2, scan_layers=True,
+                              dtype="bfloat16")
+    mesh = make_smoke_mesh()
+    placed = compiled.param_init(cfg, mesh)(jax.random.key(7))
+    want = jax.jit(init_params, static_argnums=1)(jax.random.key(7), cfg)
+    flat, tree = jax.tree_util.tree_flatten(placed)
+    assert tree == jax.tree_util.tree_structure(want)
+    for got, ref in zip(flat, jax.tree_util.tree_leaves(want)):
+        assert got.sharding.mesh == mesh
+        assert got.dtype == ref.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref, np.float32))
+
+
+_SHARDED4 = textwrap.dedent("""
+    import functools, os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import dataclasses, jax, numpy as np
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.kernels.ref import paged_decode_ref
+    from repro.launch import sharding as shlib
+    from repro.launch.mesh import make_production_mesh
+    from repro.models.attention import paged_attention
+    from repro.serving import compiled
+    from repro.serving.engine import ServeEngine
+    shape = tuple(int(n) for n in sys.argv[1].split("x"))
+    cfg = dataclasses.replace(
+        get_config("starcoder2-7b"), num_layers=3, d_model=256,
+        num_heads=8, num_kv_heads=4, head_dim=32, d_ff=512, vocab_size=512)
+    mesh = make_production_mesh(shape=shape, axes=("data", "model"))
+    params = compiled.param_init(cfg, mesh)(jax.random.key(0))
+    sharded = ServeEngine(cfg, params, max_len=64, kv_slots=2,
+                          prefill_chunk=16, mesh=mesh)
+    plain = ServeEngine(cfg, jax.device_put(params, jax.devices()[0]),
+                        max_len=64, kv_slots=2, prefill_chunk=16)
+    prompt = np.arange(20, dtype=np.int32)[None] % cfg.vocab_size
+    a = np.asarray(sharded.generate(prompt, 8).tokens)
+    b = np.asarray(plain.generate(prompt, 8).tokens)
+    pools = {str(x.sharding.spec)
+             for x in jax.tree_util.tree_leaves(sharded._paged_states)}
+    print("POOLS", pools)
+    assert pools == {"PartitionSpec(None, None, 'model')"}, pools
+    assert np.array_equal(a, b), (a, b)
+    assert sharded.kv_leak == 0
+    # the Pallas kernel itself (interpreted) inside shard_map
+    ks = jax.random.split(jax.random.key(1), 5)
+    q = jax.random.normal(ks[0], (4, 8, 32))
+    kp = jax.random.normal(ks[1], (9, 4, 8, 32))
+    vp = jax.random.normal(ks[2], (9, 4, 8, 32))
+    tables = jax.random.randint(ks[3], (4, 3), 1, 9)
+    lengths = jax.random.randint(ks[4], (4,), 1, 25)
+    ops.paged_flash_decode = functools.partial(ops.paged_flash_decode,
+                                               interpret=True)
+    with shlib.use(shlib.ShardingContext(mesh)):
+        got = jax.jit(paged_attention)(q, kp, vp, tables, lengths)
+    print("KERNEL", got.sharding.spec)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(paged_decode_ref(q, kp, vp, tables,
+                                                     lengths)),
+        atol=1e-5, rtol=1e-5)
+""")
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("shape", ["1x4", "2x2"])
+def test_sharded_paged_engine_on_four_devices_matches_unsharded(shape):
+    """On a (1, 4) or (2, 2) ("data", "model") mesh of CPU devices the
+    paged decode kernel call runs under shard_map — KV heads over
+    'model', lanes over 'data', the pools placed to match after donated
+    steps — and greedy tokens match one device; the interpreted kernel
+    under the same shard_map matches paged_decode_ref."""
+    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", _SHARDED4, shape], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "POOLS" in r.stdout and "KERNEL" in r.stdout

@@ -178,6 +178,27 @@ def test_engine_paged_matches_dense_greedy():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_paged_engine_scan_layers_bf16_matches_flat():
+    """The layout published configs serve in — bf16 weights and pools,
+    layers stacked under one scan — gives the same greedy tokens through
+    the paged engine as the same weights applied layer by layer."""
+    flat = dataclasses.replace(reduced(get_config("qwen2-1.5b")),
+                               num_layers=3, dtype="bfloat16")
+    scan = dataclasses.replace(flat, scan_layers=True)
+    prompts = jax.random.randint(jax.random.key(1), (2, 20), 0,
+                                 flat.vocab_size)
+    toks = []
+    for cfg in (flat, scan):
+        eng = ServeEngine(cfg, init_params(KEY, cfg), max_len=64,
+                          kv_slots=2, paged=True, page_size=8,
+                          prefill_chunk=8)
+        assert ("scan" in eng.params["layers"]) == cfg.scan_layers
+        toks.append(np.stack([np.asarray(t) for t in
+                              eng.generate(prompts, 6).tokens]))
+        assert eng.kv_leak == 0
+    np.testing.assert_array_equal(toks[0], toks[1])
+
+
 def test_chunked_prefill_interleaves_with_decode():
     """A short request must make decode progress BETWEEN the prefill
     chunks of a long prompt — the whole point of chunking."""

@@ -73,6 +73,7 @@ _SUBPROC = textwrap.dedent("""
     import dataclasses, jax, jax.numpy as jnp
     from repro.configs import get_config, reduced, MoEConfig
     from repro.launch import sharding as shlib
+    from repro.launch.mesh import make_production_mesh
     from repro.models import moe as moelib
     cfg = dataclasses.replace(
         reduced(get_config("mixtral-8x22b")), d_ff=96, d_model=64,
@@ -83,7 +84,7 @@ _SUBPROC = textwrap.dedent("""
     ref, _ = moelib.apply_moe(p, cfg, x)
     worst = 0.0
     for shape in [(2, 4), (4, 2), (8, 1), (1, 8)]:
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_production_mesh(shape=shape, axes=("data", "model"))
         ctx = shlib.ShardingContext(mesh)
         with mesh:
             with shlib.use(ctx):
@@ -100,7 +101,7 @@ def test_stationary_moe_matches_local_multidevice():
     import os
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
-    env = dict(os.environ, PYTHONPATH=src)
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _SUBPROC], env=env,
                        capture_output=True, text=True, timeout=600)

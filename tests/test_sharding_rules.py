@@ -1,9 +1,11 @@
 """Sharding rules: divisibility guards, expert fallbacks, state specs."""
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.launch import sharding as shlib
+from repro.launch.mesh import make_smoke_mesh
 
 
 def test_col_row_rules():
@@ -82,10 +84,33 @@ def test_state_specs_divisibility_guard():
     assert spec == P(None, None)
 
 
+@pytest.mark.parametrize("mesh_shape, kv, lanes, want_rows, want_kv", [
+    ((1, 4), 4, 8, "data", "model"),   # starcoder2-7b on four chips
+    ((2, 2), 4, 8, "data", "model"),   # lanes split, pool per data replica
+    ((2, 2), 4, 3, None, "model"),     # 3 lanes % 2: lanes replicate
+    ((1, 4), 2, 8, "data", None),      # 2 KV heads % 4: heads replicate
+])
+def test_paged_decode_pspecs(mesh_shape, kv, lanes, want_rows, want_kv):
+    """The kernel's shard_map specs follow the pool placement state_pspecs
+    gives k_pages (all pages everywhere, KV heads over 'model' where they
+    divide); query heads follow KV heads; lanes go over 'data' only
+    where it divides them."""
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh(mesh_shape, ("data", "model"))
+    shape = (4096, kv, 16, 128)
+    pool, heads, rows, lens = shlib.paged_decode_pspecs(
+        shlib.ShardingContext(mesh), lanes, shape)
+    pools = {"k_pages": jax.ShapeDtypeStruct(shape, jnp.bfloat16)}
+    assert pool == shlib.state_pspecs(pools, mesh)["k_pages"]
+    assert pool == P(None, want_kv, None, None)
+    assert heads == P(want_rows, want_kv, None)
+    assert rows == P(want_rows, None) and lens == P(want_rows)
+
+
 def test_state_shardings_on_smoke_mesh():
     """state_shardings builds NamedShardings for every leaf on the mesh."""
     from jax.sharding import NamedSharding
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_smoke_mesh()
     states = {"k": jnp.zeros((2, 4, 16, 8)), "pos": jnp.zeros(())}
     shardings = shlib.state_shardings(mesh, states)
     for leaf in jax.tree_util.tree_leaves(
@@ -109,7 +134,7 @@ def test_param_pspecs_cover_full_tree():
 
 
 def test_sharding_context_rules():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_smoke_mesh()
     ctx = shlib.ShardingContext(mesh)
     assert ctx.spec("batch", None, "ff") == P("data", None, "model")
     # no active context -> act() is a no-op

@@ -16,6 +16,7 @@ from repro.configs import get_config, get_shape, reduced
 from repro.core import env as envlib
 from repro.data.pipeline import DataConfig, synth_batch
 from repro.launch import sharding as shlib
+from repro.launch.mesh import make_smoke_mesh
 from repro.models import init_params
 from repro.train import optimizer as opt_lib
 from repro.train.steps import make_eval_step, make_train_step
@@ -61,7 +62,7 @@ def test_scheduler_over_heterogeneous_capacity():
 def test_step_functions_lower_on_mini_mesh():
     """The exact dry-run code path on a 1x1 mesh (single CPU device)."""
     from repro.launch.specs import input_specs, output_shardings
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_smoke_mesh()
     for arch in ("qwen2-1.5b", "xlstm-350m"):
         cfg = dataclasses.replace(reduced(get_config(arch)),
                                   scan_layers=True)
