@@ -1,0 +1,7 @@
+import os
+import sys
+
+_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+for _p in (os.path.join(_ROOT, "src"), _ROOT):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
