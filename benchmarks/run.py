@@ -53,11 +53,6 @@ def main() -> None:
                          "tablev,closedloop,chaos,kernels,roofline,stress")
     ap.add_argument("--out-dir", default=None,
                     help="write BENCH_<name>.json result files here")
-    ap.add_argument("--trajectory", action="store_true",
-                    help="append a per-run summary (git sha, date, knee "
-                         "goodput, p95, prefix savings) to "
-                         "BENCH_trajectory.json in --out-dir — the "
-                         "tracked perf trajectory across PRs")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -66,10 +61,7 @@ def main() -> None:
     def want(name):
         return only is None or name in only
 
-    all_records = {}
-
     def emit(name, records):
-        all_records[name] = records
         if args.out_dir is None:
             return
         os.makedirs(args.out_dir, exist_ok=True)
@@ -143,79 +135,6 @@ def main() -> None:
         print(r)
     print(f"# total bench wall time: {time.time()-t0:.1f}s",
           file=sys.stderr)
-
-    if args.trajectory:
-        if args.out_dir is None:
-            ap.error("--trajectory requires --out-dir")
-        append_trajectory(args.out_dir, args.scale, all_records)
-
-
-def _git_sha() -> str:
-    import subprocess
-    try:
-        return subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"],
-            capture_output=True, text=True, timeout=10,
-            cwd=os.path.dirname(os.path.abspath(__file__)),
-        ).stdout.strip() or "unknown"
-    except Exception:   # noqa: BLE001 — no git in the environment
-        return "unknown"
-
-
-def append_trajectory(out_dir: str, scale: str, all_records: dict) -> None:
-    """Append this run's headline numbers to ``BENCH_trajectory.json``.
-
-    The trajectory is the repo's perf record ACROSS commits: each entry
-    carries the git sha + date and, per scheduler, the stress knee
-    goodput (+ p95 at the knee) and the closed-loop mean/p95 with prefix
-    cache savings — enough to spot a regression or an improvement
-    between any two PRs without rerunning history.
-    """
-    entry = {"git_sha": _git_sha(), "scale": scale,
-             "date_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-             "benches": sorted(all_records)}
-    stress = [r for r in all_records.get("stress", [])
-              if r.get("bench") == "stress_stage"]
-    if stress:
-        knees = {}
-        for name in sorted({r["scheduler"] for r in stress}):
-            stages = [r for r in stress if r["scheduler"] == name]
-            knee = max(stages, key=lambda r: r["goodput_rps"])
-            knees[name] = {"knee_goodput_rps": knee["goodput_rps"],
-                           "knee_offered_rate": knee["offered_rate"],
-                           "knee_p95_s": knee["p95_s"]}
-        entry["stress"] = knees
-    closed = [r for r in all_records.get("closedloop", [])
-              if r.get("bench") == "closedloop_live"]
-    if closed:
-        entry["closedloop"] = {
-            r["scheduler"]: {"mean_s": r["mean_s"], "p95_s": r["p95_s"],
-                             "prefill_tokens_saved":
-                                 r.get("prefill_tokens_saved", 0),
-                             "prefix_hit_rate":
-                                 r.get("prefix_hit_rate", 0.0)}
-            for r in closed}
-    chaos = [r for r in all_records.get("chaos", [])
-             if r.get("bench") == "chaos_live"]
-    if chaos:
-        entry["chaos"] = {r["scheduler"]: r["completion_rate"]
-                          for r in chaos}
-
-    path = os.path.join(out_dir, "BENCH_trajectory.json")
-    doc = {"bench": "trajectory", "runs": []}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                prev = json.load(f)
-            if isinstance(prev.get("runs"), list):
-                doc = prev
-        except (OSError, ValueError):
-            pass            # corrupt trajectory: restart it, don't crash
-    doc["runs"].append(entry)
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=2)
-    print(f"# appended run {entry['git_sha']} to {path} "
-          f"({len(doc['runs'])} runs)", file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.cluster.request import Request
 from repro.cluster.schedulers import Scheduler
 from repro.faults import FaultEvent, FaultInjector, RetryPolicy
@@ -198,25 +199,29 @@ class EdgeCluster:
         """Scheduler picks an AVAILABLE engine; the request joins its
         queue.  Raises when every engine is DOWN — admitting into a dead
         engine would silently strand the request."""
-        avail = np.asarray([e.available for e in self.engines], bool)
-        if not avail.any():
-            raise RuntimeError(
-                f"cannot place request {req.rid}: all "
-                f"{len(self.engines)} engines are DOWN "
-                f"({[e.fail_reason for e in self.engines]})")
-        if req.t_arrival is None:
-            # first placement: anchor end-to-end delay + watchdog here so
-            # retries keep counting from the ORIGINAL arrival
-            req.t_arrival = self._clock()
-        req.attempts += 1
-        s = self.observe(req)
-        self._key, k = jax.random.split(self._key)
-        n = self._count % self.n_max
-        eng, self.carry = self.scheduler.select_one_masked(
-            self.carry, s, req.origin, n, k, avail)
-        self._count += 1
-        self.engines[eng].admit(req)
-        return eng
+        with tracing.span("repro.cluster.submit", rid=req.rid):
+            avail = np.asarray([e.available for e in self.engines], bool)
+            if not avail.any():
+                raise RuntimeError(
+                    f"cannot place request {req.rid}: all "
+                    f"{len(self.engines)} engines are DOWN "
+                    f"({[e.fail_reason for e in self.engines]})")
+            if req.t_arrival is None:
+                # first placement: anchor end-to-end delay + watchdog here
+                # so retries keep counting from the ORIGINAL arrival
+                req.t_arrival = self._clock()
+            req.attempts += 1
+            with tracing.span("repro.cluster.observe"):
+                s = self.observe(req)
+            with tracing.span("repro.scheduler.select"):
+                self._key, k = jax.random.split(self._key)
+                n = self._count % self.n_max
+                eng, self.carry = self.scheduler.select_one_masked(
+                    self.carry, s, req.origin, n, k, avail)
+            self._count += 1
+            with tracing.span("repro.engine.admit"):
+                self.engines[eng].admit(req)
+            return eng
 
     # ------------------------------------------------------------------
     # fault handling
@@ -339,12 +344,17 @@ class EdgeCluster:
         Each engine's work is isolated: an exception quarantines that
         engine (DOWN, KV reclaimed, requests re-offloaded) instead of
         unwinding the whole closed loop."""
-        now_rel = self._now_rel()
-        now = self._clock()
-        done: List[Request] = []
-        done += self._apply_faults(now_rel)
-        done += self._flush_retries(now)
-        done += self._shed_hopeless(now)
+        with tracing.span("repro.cluster.step"):
+            return self._step()
+
+    def _step(self) -> List[Request]:
+        with tracing.span("repro.cluster.faults"):
+            now_rel = self._now_rel()
+            now = self._clock()
+            done: List[Request] = []
+            done += self._apply_faults(now_rel)
+            done += self._flush_retries(now)
+            done += self._shed_hopeless(now)
         if not self.overlap:
             for i, e in enumerate(self.engines):
                 if not e.available:
@@ -397,10 +407,12 @@ class EdgeCluster:
         done: List[Request] = []
         i = 0
         # warm the scheduler's compiled select path outside the timed loop
-        # (carry deliberately discarded: no counter/latent side effects)
-        self.scheduler.select_one(
-            self.carry, jnp.zeros((self.obs_dim,), jnp.float32),
-            0, 0, jax.random.key(0))
+        # (carry deliberately discarded: no counter/latent side effects;
+        # no spans: it serves nothing)
+        with tracing.paused():
+            self.scheduler.select_one(
+                self.carry, jnp.zeros((self.obs_dim,), jnp.float32),
+                0, 0, jax.random.key(0))
         self._t0 = t0 = self._clock()
         for _ in range(max_steps):
             if i >= len(todo) and not self.busy:

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import agents as ag
 from repro.core.trainer import LEARNED, make_agent_fns
 
@@ -285,7 +286,9 @@ class PolicyScheduler(Scheduler):
             self._sel1 = jax.jit(sel1)
         a, carry = self._sel1(carry, s_row, jnp.int32(origin),
                               jnp.int32(n % self.n_max), key)
-        return int(a), carry
+        with tracing.span("repro.scheduler.sync"):
+            a = int(a)          # the host waits for the device
+        return a, carry
 
 
 BASELINES = ("round-robin", "jsq", "random", "local", "deadline",

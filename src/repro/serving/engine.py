@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.cluster.request import Request
 from repro.faults.policy import AVAILABILITY, Health
 from repro.launch import sharding as shlib
@@ -291,8 +292,10 @@ class ServeEngine:
                     return False
         if not self._queue and not self._inflight_requests():
             return False               # idle: nothing to enqueue
-        self._pending = (self._dispatch_paged() if self.paged
-                         else self._dispatch_dense())
+        with tracing.span("repro.engine.dispatch",
+                          engine=getattr(self, "engine_id", None)):
+            self._pending = (self._dispatch_paged() if self.paged
+                             else self._dispatch_dense())
         return True
 
     def collect(self) -> List[Request]:
@@ -305,6 +308,11 @@ class ServeEngine:
         produced.  A no-op (empty list) when nothing was dispatched."""
         if self._pending is None:
             return []
+        with tracing.span("repro.engine.collect",
+                          engine=getattr(self, "engine_id", None)):
+            return self._collect()
+
+    def _collect(self) -> List[Request]:
         pend, self._pending = self._pending, None
         finished: List[Request] = []
 
@@ -316,7 +324,8 @@ class ServeEngine:
         tok_np = None
         if pend.decode is not None:
             tok_all, entries = pend.decode
-            tok_np = np.asarray(tok_all)           # blocks until ready
+            with tracing.span("repro.engine.sync"):
+                tok_np = np.asarray(tok_all)       # blocks until ready
             self._note_round(self._round_t0, len(entries))
 
         # deferred prefill first-tokens (ready by now: they were enqueued
@@ -406,10 +415,59 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def _dispatch_paged(self) -> _Pending:
         pend = _Pending()
-        # 1. admission — head-of-line, gated on free pages (worst case
-        # reserved up front) and a free lane.  The queue drains in
-        # priority/EDF order (exact FIFO without QoS classes); no
-        # skipping past the ordered head.
+        with tracing.span("repro.engine.admission") as sp:
+            forks = self.cow_forks
+            admitted = self._admit_paged()
+            sp.set(admitted=admitted, forks=self.cow_forks - forks)
+        # one prefill chunk per still-prefilling lane (device enqueue
+        # only; last-chunk first-tokens sync in collect())
+        self._ensure_paged_states()
+        C = self.prefill_chunk
+        for i, lane in enumerate(self._lanes):
+            if lane is None or lane.decoding:
+                continue
+            req = lane.req
+            c0 = lane.chunk_pos
+            with tracing.span("repro.engine.prefill", rid=req.rid, lane=i,
+                              start=c0,
+                              tokens=min(C, lane.prompt_len - c0)):
+                self._prefill_chunk(pend, i, lane)
+
+        # one decode round across the lanes that finished prefilling;
+        # idle/prefilling lanes ride along masked (null table, length 0)
+        active = [i for i, ln in enumerate(self._lanes)
+                  if ln is not None and ln.decoding]
+        if active:
+            with tracing.span("repro.engine.decode_inputs"):
+                batch, context = self._decode_inputs(active)
+                key = self._next_key()
+            self._round_t0 = self._clock()
+            with tracing.span("repro.engine.decode", lanes=len(active),
+                              lengths=context), self._sharded():
+                _, tok_all, self._paged_states = self._paged_decode(
+                    self.params, batch, self._paged_states, key)
+            entries = []
+            for i in active:
+                lane = self._lanes[i]
+                req = lane.req
+                tk = tok_all[i:i + 1]              # device slice, lazy
+                pos = len(req.tokens)
+                req.tokens.append(tk)
+                lane.last_tok = tk
+                lane.length += 1                   # decode wrote one KV
+                fin = len(req.tokens) >= req.max_new_tokens
+                entries.append((i, req, pos, fin))
+                if fin:
+                    self._free_lane(i)
+            pend.decode = (tok_all, entries)
+        return pend
+
+    def _admit_paged(self) -> int:
+        """Admission — head-of-line, gated on free pages (worst case
+        reserved up front) and a free lane.  The queue drains in
+        priority/EDF order (exact FIFO without QoS classes); no skipping
+        past the ordered head.  Returns the requests admitted."""
+        admitted = 0
         free = [i for i, ln in enumerate(self._lanes) if ln is None]
         while free and self._queue:
             req = self._queue[0]
@@ -466,94 +524,71 @@ class ServeEngine:
                 req.prefix_tokens = matched
             self._lanes[i] = _Lane(req=req, table=table, prompt_len=plen,
                                    chunk_pos=matched, length=matched)
+            admitted += 1
         self._note_inflight(sum(ln is not None for ln in self._lanes))
+        return admitted
 
-        # 2. one prefill chunk per still-prefilling lane (device enqueue
-        # only; last-chunk first-tokens sync in collect())
-        self._ensure_paged_states()
-        C = self.prefill_chunk
-        for i, lane in enumerate(self._lanes):
-            if lane is None or lane.decoding:
-                continue
-            req = lane.req
-            if req.t_prefill_start is None:    # first chunk (chunk_pos may
-                req.t_prefill_start = self._clock()  # start past 0 on a hit)
-            c0 = lane.chunk_pos
-            chunk = np.asarray(req.prompt[..., c0:c0 + C])
-            pad = C - chunk.shape[-1]
-            if pad:
-                widths = [(0, 0)] * (chunk.ndim - 1) + [(0, pad)]
-                chunk = np.pad(chunk, widths)
-            row = jnp.asarray(lane.table.row(self._row_width), jnp.int32)
+    def _prefill_chunk(self, pend: _Pending, i: int, lane: _Lane) -> None:
+        """Enqueue lane ``i``'s next prompt chunk; on the prompt's last
+        chunk, its first token (synced in collect())."""
+        req, C = lane.req, self.prefill_chunk
+        if req.t_prefill_start is None:        # first chunk (chunk_pos may
+            req.t_prefill_start = self._clock()    # start past 0 on a hit)
+        c0 = lane.chunk_pos
+        chunk = np.asarray(req.prompt[..., c0:c0 + C])
+        pad = C - chunk.shape[-1]
+        if pad:
+            widths = [(0, 0)] * (chunk.ndim - 1) + [(0, pad)]
+            chunk = np.pad(chunk, widths)
+        row = jnp.asarray(lane.table.row(self._row_width), jnp.int32)
+        with self._sharded():
+            logits, self._paged_states = self._paged_prefill(
+                self.params,
+                {"tokens": jnp.asarray(chunk, jnp.int32),
+                 "start": jnp.asarray(c0, jnp.int32),
+                 "block_table": row},
+                self._paged_states)
+        lane.chunk_pos = c0 + C
+        lane.length = min(lane.chunk_pos, lane.prompt_len)
+        if lane.decoding:                      # last chunk of prompt
+            last = lane.prompt_len - 1 - c0
             with self._sharded():
-                logits, self._paged_states = self._paged_prefill(
-                    self.params,
-                    {"tokens": jnp.asarray(chunk, jnp.int32),
-                     "start": jnp.asarray(c0, jnp.int32),
-                     "block_table": row},
-                    self._paged_states)
-            lane.chunk_pos = c0 + C
-            lane.length = min(lane.chunk_pos, lane.prompt_len)
-            if lane.decoding:                      # last chunk of prompt
-                last = lane.prompt_len - 1 - c0
-                with self._sharded():
-                    tok = self._pick(logits[0, last][None])
-                pos = len(req.tokens)
-                req.tokens.append(tok)
-                lane.last_tok = tok
-                fin = len(req.tokens) >= req.max_new_tokens
-                pend.prefill.append((req, tok, pos, fin))
-                # the prompt's KV is complete: index every full prompt
-                # block so later prompts with the same chain reuse it
-                # (insert BEFORE any lane release so cached pages carry
-                # their reference when the lane lets go)
-                if self._prefix is not None:
-                    self._prefix.insert(req.prompt, lane.table.pages)
-                if fin:
-                    self._free_lane(i)
+                tok = self._pick(logits[0, last][None])
+            pos = len(req.tokens)
+            req.tokens.append(tok)
+            lane.last_tok = tok
+            fin = len(req.tokens) >= req.max_new_tokens
+            pend.prefill.append((req, tok, pos, fin))
+            # the prompt's KV is complete: index every full prompt
+            # block so later prompts with the same chain reuse it
+            # (insert BEFORE any lane release so cached pages carry
+            # their reference when the lane lets go)
+            if self._prefix is not None:
+                self._prefix.insert(req.prompt, lane.table.pages)
+            if fin:
+                self._free_lane(i)
 
-        # 3. one decode round across the lanes that finished prefilling;
-        # idle/prefilling lanes ride along masked (null table, length 0)
-        active = [i for i, ln in enumerate(self._lanes)
-                  if ln is not None and ln.decoding]
-        if active:
-            L, W = self.max_lanes, self._row_width
-            rows = [self._zero_tok] * L
-            tables = np.zeros((L, W), np.int32)
-            lengths = np.zeros((L,), np.int32)
-            for i in active:
-                lane = self._lanes[i]
-                rows[i] = lane.last_tok        # may be a device array
-                tables[i] = lane.table.row(W)
-                lengths[i] = lane.length
-            toks = jnp.stack([jnp.asarray(r, jnp.int32) for r in rows])
-            if self.cfg.num_codebooks:
-                tok_in = jnp.transpose(toks, (0, 2, 1))  # (L,1,K)->(L,K,1)
-            else:
-                tok_in = toks                      # (L,1)
-            self._round_t0 = self._clock()
-            with self._sharded():
-                _, tok_all, self._paged_states = self._paged_decode(
-                    self.params,
-                    {"tokens": tok_in,
-                     "block_tables": jnp.asarray(tables, jnp.int32),
-                     "lengths": jnp.asarray(lengths, jnp.int32)},
-                    self._paged_states, self._next_key())
-            entries = []
-            for i in active:
-                lane = self._lanes[i]
-                req = lane.req
-                tk = tok_all[i:i + 1]              # device slice, lazy
-                pos = len(req.tokens)
-                req.tokens.append(tk)
-                lane.last_tok = tk
-                lane.length += 1                   # decode wrote one KV
-                fin = len(req.tokens) >= req.max_new_tokens
-                entries.append((i, req, pos, fin))
-                if fin:
-                    self._free_lane(i)
-            pend.decode = (tok_all, entries)
-        return pend
+    def _decode_inputs(self, active: List[int]):
+        """The decode round's batch over all lanes (idle and prefilling
+        lanes masked: null table, length 0), and the context length, in
+        keys attended, of each active lane."""
+        L, W = self.max_lanes, self._row_width
+        rows = [self._zero_tok] * L
+        tables = np.zeros((L, W), np.int32)
+        lengths = np.zeros((L,), np.int32)
+        context = []
+        for i in active:
+            lane = self._lanes[i]
+            rows[i] = lane.last_tok            # may be a device array
+            tables[i] = lane.table.row(W)
+            lengths[i] = lane.length
+            context.append(lane.length + 1)    # with the token fed now
+        toks = jnp.stack([jnp.asarray(r, jnp.int32) for r in rows])
+        if self.cfg.num_codebooks:
+            toks = jnp.transpose(toks, (0, 2, 1))  # (L,1,K)->(L,K,1)
+        return ({"tokens": toks,
+                 "block_tables": jnp.asarray(tables, jnp.int32),
+                 "lengths": jnp.asarray(lengths, jnp.int32)}, context)
 
     def run_to_completion(self, max_steps: int = 1_000_000) -> List[Request]:
         """Step until queue and slots drain; returns finished requests."""
