@@ -27,11 +27,12 @@ KEY = jax.random.key(0)
 _MODE = os.environ.get("REPRO_PAGED_TEST_MODE", "interpret")
 
 
-def _kernel_paged_decode(q, kp, vp, tbl, lens):
+def _kernel_paged_decode(q, kp, vp, tbl, lens, pages_per_block=None):
     if _MODE == "default":
         from repro.kernels import ops
         return ops.paged_flash_decode(q, kp, vp, tbl, lens, interpret=None)
-    return paged_flash_decode(q, kp, vp, tbl, lens, interpret=True)
+    return paged_flash_decode(q, kp, vp, tbl, lens, interpret=True,
+                              pages_per_block=pages_per_block)
 
 
 # ---------------------------------------------------------------------------
@@ -39,20 +40,25 @@ def _kernel_paged_decode(q, kp, vp, tbl, lens):
 # ---------------------------------------------------------------------------
 
 
-def _paged_case(B, KV, H, hd, ps, npages, lengths, seed=0):
+def _paged_case(B, KV, H, hd, ps, npages, lengths, seed=0,
+                dtype=jnp.float32):
     """Random pool + per-sequence scrambled page tables, plus the dense
-    (B, KV, S, hd) cache holding the same tokens in order."""
+    (B, KV, S, hd) float32 cache holding the same tokens in order.  q and
+    the pools come in ``dtype``; the dense view holds their values."""
     rng = np.random.default_rng(seed)
     pool_pages = 1 + B * npages                    # page 0 = null
     perm = 1 + rng.permutation(B * npages)         # scrambled, non-contig
     tables = perm.reshape(B, npages).astype(np.int32)
     ks = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(ks[0], (B, H, hd), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (pool_pages, KV, ps, hd), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (pool_pages, KV, ps, hd), jnp.float32)
+    q = jax.random.normal(ks[0], (B, H, hd), jnp.float32).astype(dtype)
+    k_pages = jax.random.normal(ks[1], (pool_pages, KV, ps, hd),
+                                jnp.float32).astype(dtype)
+    v_pages = jax.random.normal(ks[2], (pool_pages, KV, ps, hd),
+                                jnp.float32).astype(dtype)
     # dense view: logical position p of sequence b lives at
     # page tables[b, p // ps], slot p % ps
-    kp, vp = np.asarray(k_pages), np.asarray(v_pages)
+    kp = np.asarray(k_pages.astype(jnp.float32))
+    vp = np.asarray(v_pages.astype(jnp.float32))
     S = npages * ps
     kd = np.stack([kp[tables[b]].transpose(1, 0, 2, 3).reshape(KV, S, hd)
                    for b in range(B)])
@@ -63,37 +69,70 @@ def _paged_case(B, KV, H, hd, ps, npages, lengths, seed=0):
             jnp.asarray(vd))
 
 
-def test_paged_kernel_matches_dense_ref_ragged_scrambled():
+# (B, KV, H, hd, ps, npages, lengths, pages_per_block, dtype): lengths end
+# mid-page, fill the row exactly, span several blocks, and sit beside
+# length-1 lanes (the engine's idle lanes)
+_KERNEL_CASES = {
+    # qwen2's grouping (KV 2, G 6); the derived block holds the whole row
+    "qwen2-heads": (4, 2, 12, 64, 8, 6, [1, 7, 23, 48], None, "float32"),
+    # the same lanes in 4-page blocks: 6 pages is not a multiple of 4
+    "qwen2-heads-small-blocks": (4, 2, 12, 64, 8, 6, [1, 7, 23, 48], 4,
+                                 "float32"),
+    # starcoder2-7b's grouping on one chip of four (KV 1, G 9)
+    "starcoder2-chip-heads": (5, 1, 9, 32, 8, 7, [1, 56, 13, 1, 30], 2,
+                              "float32"),
+    # one page per block: the prefetch crosses a block at every page
+    "one-page-blocks": (3, 2, 4, 32, 8, 5, [40, 1, 17], 1, "float32"),
+    # bf16 pools and query against the float32 oracle
+    "bf16-pools": (4, 2, 12, 64, 16, 5, [1, 80, 33, 1], 2, "bfloat16"),
+    # empty lanes first, between and last: the prefetch skips them
+    "empty-lanes": (6, 2, 4, 32, 8, 5, [0, 30, 0, 0, 9, 0], 2, "float32"),
+}
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_paged_kernel_matches_dense_ref_ragged_scrambled(case):
     """Kernel (mode per REPRO_PAGED_TEST_MODE) vs the dense decode
-    oracle: ragged lengths (including a partial last page and a
-    single-token sequence) through deliberately non-contiguous page
-    tables."""
-    B, KV, H, hd, ps, npages = 4, 2, 8, 64, 8, 6
-    lengths = [1, 7, 23, 48]        # mid-page, full, ragged, exactly full
+    oracle through deliberately non-contiguous page tables."""
+    B, KV, H, hd, ps, npages, lengths, ppb, dtype = _KERNEL_CASES[case]
     q, kp, vp, tbl, lens, kd, vd = _paged_case(B, KV, H, hd, ps, npages,
-                                               lengths)
-    got = _kernel_paged_decode(q, kp, vp, tbl, lens)
-    want = ref.decode_ref(q, kd, vd, lens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-3, rtol=1e-3)
+                                               lengths, dtype=dtype)
+    got = _kernel_paged_decode(q, kp, vp, tbl, lens, pages_per_block=ppb)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got = np.asarray(got.astype(jnp.float32))
+    want = np.asarray(ref.decode_ref(q.astype(jnp.float32), kd, vd, lens))
+    # the oracle spreads an empty lane's softmax evenly; the kernel
+    # returns zeros there
+    live = np.asarray(lengths) > 0
+    if _MODE == "interpret":
+        assert not got[~live].any()
+    tol = 1e-3 if dtype == "float32" else 2e-2      # bf16 output rounding
+    np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
     # and the XLA serving-path oracle agrees with both
-    ora = ref.paged_decode_ref(q, kp, vp, tbl, lens)
-    np.testing.assert_allclose(np.asarray(ora), np.asarray(want),
-                               atol=1e-3, rtol=1e-3)
+    ora = ref.paged_decode_ref(q.astype(jnp.float32),
+                               kp.astype(jnp.float32),
+                               vp.astype(jnp.float32), tbl, lens)
+    np.testing.assert_allclose(np.asarray(ora), want, atol=1e-3, rtol=1e-3)
 
 
-def test_paged_kernel_ignores_unmapped_table_entries():
-    """Entries past ceil(length/ps) may point anywhere (here: all at the
-    null page) without changing the output."""
-    B, KV, H, hd, ps, npages = 2, 2, 4, 32, 8, 4
-    lengths = [9, 17]
+@pytest.mark.parametrize("fill", ["null-page", "out-of-range"])
+def test_paged_kernel_ignores_unmapped_table_entries(fill):
+    """Entries past ceil(length/ps) may point anywhere (the null page, or
+    ids outside the pool) without changing the output."""
+    B, KV, H, hd, ps, npages = 3, 2, 4, 32, 8, 5
+    lengths = [9, 17, 1]
     q, kp, vp, tbl, lens, kd, vd = _paged_case(B, KV, H, hd, ps, npages,
                                                lengths, seed=3)
-    base = _kernel_paged_decode(q, kp, vp, tbl, lens)
+    base = _kernel_paged_decode(q, kp, vp, tbl, lens, pages_per_block=2)
     tbl2 = np.asarray(tbl).copy()
     for b, ln in enumerate(lengths):
-        tbl2[b, -(-ln // ps):] = 0                 # null out unmapped tail
-    got = _kernel_paged_decode(q, kp, vp, jnp.asarray(tbl2), lens)
+        if fill == "null-page":
+            tbl2[b, -(-ln // ps):] = 0
+        else:
+            tbl2[b, -(-ln // ps):] = kp.shape[0] + 1000 * (b + 1)
+    tbl2[2, -1] = -7 if fill == "out-of-range" else 0
+    got = _kernel_paged_decode(q, kp, vp, jnp.asarray(tbl2), lens,
+                               pages_per_block=2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(base),
                                atol=1e-6, rtol=1e-6)
 

@@ -69,17 +69,32 @@ def _decode_inputs(cfg, sharding):
     return _placed(batch, sharding)
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("arch", ["qwen2-1.5b", "starcoder2-3b"])
-def test_paged_flash_decode_compiles(one_chip, arch, dtype):
+_KERNEL_SHAPES = [
+    pytest.param(arch, dtype, LANES, ROW, PAGES, 1, id=f"{arch}-{dtype}")
+    for arch in ("qwen2-1.5b", "starcoder2-3b")
+    for dtype in ("bfloat16", "float32")
+] + [
+    # the benchmark's cells: chat's 149-page rows, and starcoder2-7b's
+    # share of each chip on a (1, 4) mesh (9 query heads, one KV head)
+    pytest.param("qwen2-1.5b", "bfloat16", 16, 149, 4096, 1,
+                 id="qwen2-1.5b-chat-row"),
+    pytest.param("starcoder2-7b", "bfloat16", 16, 133, 8193, 4,
+                 id="starcoder2-7b-per-chip"),
+]
+
+
+@pytest.mark.parametrize("arch,dtype,lanes,row,pages,chips", _KERNEL_SHAPES)
+def test_paged_flash_decode_compiles(one_chip, arch, dtype, lanes, row,
+                                     pages, chips):
     cfg = get_config(arch)
-    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    H, KV = cfg.num_heads // chips, cfg.num_kv_heads // chips
+    hd = cfg.head_dim
     args = _placed(
-        (jax.ShapeDtypeStruct((LANES, H, hd), dtype),
-         jax.ShapeDtypeStruct((PAGES, KV, PAGE, hd), dtype),
-         jax.ShapeDtypeStruct((PAGES, KV, PAGE, hd), dtype),
-         jax.ShapeDtypeStruct((LANES, ROW), jnp.int32),
-         jax.ShapeDtypeStruct((LANES,), jnp.int32)), one_chip)
+        (jax.ShapeDtypeStruct((lanes, H, hd), dtype),
+         jax.ShapeDtypeStruct((pages, KV, PAGE, hd), dtype),
+         jax.ShapeDtypeStruct((pages, KV, PAGE, hd), dtype),
+         jax.ShapeDtypeStruct((lanes, row), jnp.int32),
+         jax.ShapeDtypeStruct((lanes,), jnp.int32)), one_chip)
     kernel = functools.partial(paged_flash_decode, interpret=False)
     compiled = jax.jit(kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
