@@ -4,7 +4,8 @@ from __future__ import annotations
 import importlib
 from typing import Dict, List
 
-from repro.configs.base import BlockSpec, ModelConfig, MoEConfig, reduced
+from repro.configs.base import (BlockSpec, ModelConfig, MoEConfig, YarnConfig,
+                                reduced)
 from repro.configs.shapes import (SHAPES, SHAPES_BY_NAME, InputShape,
                                   get_shape, smoke_shape)
 
@@ -20,6 +21,8 @@ _ARCH_MODULES: Dict[str, str] = {
     "recurrentgemma-9b": "repro.configs.recurrentgemma_9b",
     "llava-next-mistral-7b": "repro.configs.llava_next_mistral_7b",
     "qwen2-1.5b": "repro.configs.qwen2_1_5b",
+    "mellum2-12b-a2.5b": "repro.configs.mellum2_12b",
+    "mellum2-12b-a2.5b-d12": "repro.configs.mellum2_12b_d12",
 }
 
 ARCH_IDS: List[str] = list(_ARCH_MODULES)
@@ -37,6 +40,6 @@ def all_configs() -> Dict[str, ModelConfig]:
 
 __all__ = [
     "ARCH_IDS", "BlockSpec", "InputShape", "ModelConfig", "MoEConfig",
-    "SHAPES", "SHAPES_BY_NAME", "all_configs", "get_config", "get_shape",
-    "reduced", "smoke_shape",
+    "SHAPES", "SHAPES_BY_NAME", "YarnConfig", "all_configs", "get_config",
+    "get_shape", "reduced", "smoke_shape",
 ]

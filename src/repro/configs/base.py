@@ -39,6 +39,7 @@ class BlockSpec:
     ffn: str = "dense"           # dense | moe | none
     window: Optional[int] = None  # sliding/local attention window (tokens)
     use_rope: bool = True        # rotary embeddings (attn only)
+    yarn: bool = False           # YaRN-scaled rotary (the config's yarn)
 
     def is_recurrent(self) -> bool:
         return self.mixer in ("mlstm", "slstm", "rglru")
@@ -50,6 +51,21 @@ class MoEConfig:
     top_k: int = 2
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance auxiliary loss weight
+    d_expert: int = 0            # expert FFN width; 0 -> the model's d_ff
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (HF ``_compute_yarn_parameters``): inverse
+    frequencies ramped between the interpolated and the original ones
+    over the beta_fast..beta_slow correction range, and cos/sin scaled by
+    ``attention_factor``."""
+
+    factor: float = 16.0
+    original_max_position_embeddings: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,10 +93,16 @@ class ModelConfig:
     long_context_window: Optional[int] = None  # beyond-paper SW variant used
     # only for the long_500k serving shape on otherwise-full-attention archs.
 
+    # YaRN scaling of the rotary embedding on "full" attention positions
+    yarn: Optional[YarnConfig] = None
+
     # layer pattern -----------------------------------------------------------
     # pattern of block templates, tiled to num_layers.  Encoded as a tuple of
-    # (mixer, ffn) pairs; window defaults resolved in layer_pattern().
-    pattern: Tuple[Tuple[str, str], ...] = (("attn", "dense"),)
+    # (mixer, ffn) pairs; window defaults resolved in layer_pattern().  An
+    # attention position may carry a third element, its kind: "sliding"
+    # (window ``sliding_window``, plain RoPE) or "full" (no window, YaRN
+    # when ``yarn`` is set).
+    pattern: Tuple[Tuple[str, ...], ...] = (("attn", "dense"),)
 
     # ffn behaviour -----------------------------------------------------------
     gated_ffn: bool = True       # SwiGLU-style gated MLP
@@ -143,16 +165,24 @@ class ModelConfig:
         """Expand ``pattern`` to ``num_layers`` BlockSpecs."""
         blocks = []
         for i in range(self.num_layers):
-            mixer, ffn = self.pattern[i % len(self.pattern)]
-            window = None
-            if mixer == "attn":
+            mixer, ffn, *kind = self.pattern[i % len(self.pattern)]
+            window, yarn = None, False
+            if kind:
+                window = self.sliding_window if kind[0] == "sliding" else None
+                yarn = kind[0] == "full" and self.yarn is not None
+            elif mixer == "attn":
                 if self.family == "hybrid" and len(self.pattern) > 1:
                     window = self.local_window
                 elif self.sliding_window is not None:
                     window = self.sliding_window
             blocks.append(BlockSpec(mixer=mixer, ffn=ffn, window=window,
-                                    use_rope=self.use_rope))
+                                    use_rope=self.use_rope, yarn=yarn))
         return tuple(blocks)
+
+    @property
+    def d_expert(self) -> int:
+        """Width of one expert's FFN."""
+        return (self.moe.d_expert or self.d_ff) if self.moe else 0
 
     # ------------------------------------------------------------------
     @property
@@ -207,7 +237,7 @@ class ModelConfig:
                 n += mult * d * f
             elif blk.ffn == "moe":
                 mult = 3 if self.gated_ffn else 2
-                n += self.moe.num_experts * mult * d * f
+                n += self.moe.num_experts * mult * d * self.d_expert
                 n += d * self.moe.num_experts        # router
             n += 2 * d                               # 2 norms
         return n
@@ -216,7 +246,7 @@ class ModelConfig:
         """Params touched per token (MoE uses top_k of num_experts)."""
         if self.moe is None:
             return self.param_count()
-        d, f = self.d_model, self.d_ff
+        d, f = self.d_model, self.d_expert
         mult = 3 if self.gated_ffn else 2
         dense_like = self.param_count()
         n_moe_blocks = sum(1 for b in self.layer_pattern() if b.ffn == "moe")
@@ -235,7 +265,8 @@ def reduced(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256,
     if cfg.moe is not None:
         moe = MoEConfig(num_experts=min(cfg.moe.num_experts, max_experts),
                         top_k=min(cfg.moe.top_k, 2),
-                        capacity_factor=cfg.moe.capacity_factor)
+                        capacity_factor=cfg.moe.capacity_factor,
+                        d_expert=d_model // 2 if cfg.moe.d_expert else 0)
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",

@@ -31,7 +31,10 @@ Two cache layouts share the same online-softmax inner loop:
     block tables and lengths are scalar-prefetched to SMEM, so only pages
     below ceil(length / page_size) are ever copied or read from the
     table: a lane of length 1 costs one page, and the loop runs only over
-    live blocks.  ``length`` masks the tail of the last live page.
+    live blocks.  ``length`` masks the tail of the last live page.  With
+    a ``window`` a lane's loop starts at the page holding key position
+    length - window: pages wholly before it are never copied, and the
+    keys before it in that page are masked.
 """
 from __future__ import annotations
 
@@ -144,13 +147,17 @@ def _pages_per_block(page_size: int, pages_per_seq: int) -> int:
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                          k_buf, v_buf, sems, slot_ref, m_scr, l_scr, acc_scr,
                          *, scale: float, ps: int, ppb: int, npages: int,
-                         lanes: int):
+                         lanes: int, window):
     b = pl.program_id(0)
     KV, hd = k_buf.shape[2], k_buf.shape[4]
     T = ppb * ps
 
     def length_of(lane):
         return jnp.minimum(len_ref[lane], npages * ps)
+
+    def first_page(lane):
+        """The lane's first page in its window (0 without a window)."""
+        return jnp.maximum(length_of(lane) - window, 0) // ps
 
     def block_copies(lane, blk, slot, wait):
         """Start (or wait for) the copies of block ``blk`` of ``lane``
@@ -159,12 +166,15 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         the whole buffer; the lane's last, partial block loops over its
         live pages (a branch per page costs about what its DMA does)."""
         live = (length_of(lane) + ps - 1) // ps
-        n = jnp.minimum(live - blk * ppb, ppb)
+        base = blk * ppb
+        if window is not None:
+            base = base + first_page(lane)
+        n = jnp.minimum(live - base, ppb)
         pools = ((k_hbm, k_buf, sems.at[0, slot]),
                  (v_hbm, v_buf, sems.at[1, slot]))
 
         def page(i, carry):
-            phys = tbl_ref[lane * npages + blk * ppb + i]
+            phys = tbl_ref[lane * npages + base + i]
             for pool, buf, sem in pools:
                 copy = pltpu.make_async_copy(pool.at[phys], buf.at[slot, i],
                                              sem)
@@ -204,7 +214,12 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             block_copies(lane0, 0, 0, wait=False)
 
     length = length_of(b)
-    nblk = (length + T - 1) // T
+    if window is None:
+        p0 = 0
+        nblk = (length + T - 1) // T
+    else:
+        p0 = first_page(b)
+        nblk = ((length + ps - 1) // ps - p0 + ppb - 1) // ppb
     after = next_live_lane(b)
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -222,7 +237,7 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             slot_ref[0] = 1 - slot
 
         block_copies(b, j, slot, wait=True)
-        k_start = j * T
+        k_start = j * T if window is None else (p0 + j * ppb) * ps
         q = q_ref[0]                                       # (KV, G, hd)
         k = jnp.stack([k_buf[slot, :, h].reshape(T, hd)
                        for h in range(KV)])                # (KV, T, hd)
@@ -239,7 +254,10 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             q.astype(dt), k.astype(dt), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale    # (KV, G, T)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(kpos < length, s, NEG_INF)
+        live = kpos < length
+        if window is not None:
+            live &= kpos >= length - window
+        s = jnp.where(live, s, NEG_INF)
 
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
@@ -260,7 +278,8 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
                        interpret: bool = False,
-                       pages_per_block: int | None = None) -> jnp.ndarray:
+                       pages_per_block: int | None = None,
+                       window: int | None = None) -> jnp.ndarray:
     """Flash decode over a shared KV page pool.
 
     q            (B, H, hd) one query token per sequence.
@@ -272,6 +291,8 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
     lengths      () or (B,) valid tokens per sequence.
     pages_per_block  pages per block; derived from the page size and the
                  row width when None (tests force small blocks).
+    window       None: attend every key below the length; else only the
+                 last ``window`` keys (positions length - window on).
 
     Returns (B, H, hd).  A sequence with length 0 returns zeros.
     """
@@ -287,7 +308,8 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
     lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale, ps=ps,
-                               ppb=ppb, npages=npages, lanes=B)
+                               ppb=ppb, npages=npages, lanes=B,
+                               window=window)
     lane_block = pl.BlockSpec((1, KV, G, hd),
                               lambda b, tbl, lens: (b, 0, 0, 0))
     buf = pltpu.VMEM((2, ppb, KV, ps, hd), k_pages.dtype)

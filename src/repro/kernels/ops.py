@@ -49,9 +49,10 @@ def flash_decode(q, k_cache, v_cache, length, *, bk: int = 512,
                          interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
-                       interpret: Optional[bool] = None) -> jnp.ndarray:
+                       interpret: Optional[bool] = None,
+                       window: Optional[int] = None) -> jnp.ndarray:
     """Paged flash decode (see kernels.decode_attention.paged_flash_decode).
 
     ``interpret=None`` picks the serving-sensible path per backend: the
@@ -64,10 +65,10 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
     if interpret is None:
         if _default_interpret():
             return _ref.paged_decode_ref(q, k_pages, v_pages, block_tables,
-                                         lengths)
+                                         lengths, window)
         interpret = False
     return _paged_flash_decode(q, k_pages, v_pages, block_tables, lengths,
-                               interpret=interpret)
+                               interpret=interpret, window=window)
 
 
 # ---------------------------------------------------------------------------

@@ -34,24 +34,29 @@ def attention_ref(q, k, v, *, causal: bool = True,
     return out.astype(q.dtype)
 
 
-def decode_ref(q, k_cache, v_cache, length) -> jnp.ndarray:
-    """q (B,H,hd); caches (B,KV,S,hd); length () or (B,)."""
+def decode_ref(q, k_cache, v_cache, length,
+               window: Optional[int] = None) -> jnp.ndarray:
+    """q (B,H,hd); caches (B,KV,S,hd); length () or (B,); ``window``
+    keeps only the last ``window`` keys below the length."""
     B, H, hd = q.shape
     KV, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     qh = q.reshape(B, KV, G, hd).astype(jnp.float32)
     s = jnp.einsum("bkgd,bksd->bkgs", qh, k_cache.astype(jnp.float32))
     s = s / jnp.sqrt(1.0 * hd)
-    valid = (jnp.arange(S)[None, :]
-             < jnp.broadcast_to(jnp.asarray(length), (B,))[:, None])
+    pos = jnp.arange(S)[None, :]
+    lens = jnp.broadcast_to(jnp.asarray(length), (B,))[:, None]
+    valid = pos < lens
+    if window is not None:
+        valid &= pos >= lens - window
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bksd->bkgd", w, v_cache.astype(jnp.float32))
     return out.reshape(B, H, hd).astype(q.dtype)
 
 
-def paged_decode_ref(q, k_pages, v_pages, block_tables, lengths
-                     ) -> jnp.ndarray:
+def paged_decode_ref(q, k_pages, v_pages, block_tables, lengths,
+                     window: Optional[int] = None) -> jnp.ndarray:
     """Paged decode oracle: gather pages dense, then :func:`decode_ref`.
 
     q (B,H,hd); k/v_pages (P,KV,ps,hd); block_tables (B,npages) int32;
@@ -65,7 +70,7 @@ def paged_decode_ref(q, k_pages, v_pages, block_tables, lengths
     v = v_pages[tbl]
     k = k.transpose(0, 2, 1, 3, 4).reshape(B, KV, npages * ps, hd)
     v = v.transpose(0, 2, 1, 3, 4).reshape(B, KV, npages * ps, hd)
-    return decode_ref(q, k, v, lengths)
+    return decode_ref(q, k, v, lengths, window)
 
 
 def ladn_denoise_ref(x_I, s, noise, temb_w1, w1x, w1s, b1, w2, b2, w3, b3,

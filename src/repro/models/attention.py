@@ -51,8 +51,9 @@ def init_attention(key, cfg, dtype) -> dict:
     return p
 
 
-def _project_qkv(p, cfg, x, positions, use_rope):
-    """x (B,S,d) -> q (B,S,H,hd), k/v (B,S,KV,hd), with RoPE applied."""
+def _project_qkv(p, cfg, x, positions, blk):
+    """x (B,S,d) -> q (B,S,H,hd), k/v (B,S,KV,hd), with the block's
+    RoPE applied (YaRN-scaled on ``blk.yarn`` positions)."""
     B, S, _ = x.shape
     hd = cfg.head_dim
     q = x @ p["wq"]
@@ -66,9 +67,10 @@ def _project_qkv(p, cfg, x, positions, use_rope):
     q = sharding.act(q, "batch", "seq", "heads", None)
     k = sharding.act(k, "batch", "seq", "kv_heads", None)
     v = sharding.act(v, "batch", "seq", "kv_heads", None)
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+    if blk.use_rope:
+        yarn = cfg.yarn if blk.yarn else None
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
     return q, k, v
 
 
@@ -158,7 +160,7 @@ def chunked_causal_attention(q, k, v, *, window: Optional[int] = None,
 def attend_train(p, cfg, blk, x, positions) -> jnp.ndarray:
     """Full-sequence causal attention (train / prefill forward)."""
     B, S, _ = x.shape
-    q, k, v = _project_qkv(p, cfg, x, positions, blk.use_rope)
+    q, k, v = _project_qkv(p, cfg, x, positions, blk)
     out = chunked_causal_attention(q, k, v, window=blk.window,
                                    q_chunk=cfg.attn_chunk,
                                    kv_chunk=cfg.attn_chunk)
@@ -214,7 +216,7 @@ def prefill(p, cfg, blk, x, positions, max_len: Optional[int] = None
     position p stored at slot p % W (matching :func:`decode`).
     """
     B, S, _ = x.shape
-    q, k, v = _project_qkv(p, cfg, x, positions, blk.use_rope)
+    q, k, v = _project_qkv(p, cfg, x, positions, blk)
     out = chunked_causal_attention(q, k, v, window=blk.window,
                                    q_chunk=cfg.attn_chunk,
                                    kv_chunk=cfg.attn_chunk)
@@ -265,12 +267,14 @@ def prefill(p, cfg, blk, x, positions, max_len: Optional[int] = None
 
 def init_paged_kv_cache(cfg, blk, num_pages: int, page_size: int,
                         make=jnp.zeros):
-    """Empty per-layer page pool.  ``make`` may be jax.ShapeDtypeStruct."""
-    if blk.window is not None or cfg.kv_cache_dtype == "int8":
+    """Empty per-layer page pool.  ``make`` may be jax.ShapeDtypeStruct.
+
+    A windowed layer keeps every page of its lanes, as a full one does;
+    its reads start at the window (``paged_flash_decode(window=)``)."""
+    if cfg.kv_cache_dtype == "int8":
         raise ValueError(
-            "paged KV serving supports full-attention model-dtype caches "
-            f"only (window={blk.window}, kv_cache_dtype="
-            f"{cfg.kv_cache_dtype})")
+            "paged KV serving supports model-dtype caches only "
+            f"(kv_cache_dtype={cfg.kv_cache_dtype})")
     dtype = _cache_dtype(cfg)
     kv = cfg.num_kv_heads
     return {"k_pages": make((num_pages, kv, page_size, cfg.head_dim), dtype),
@@ -310,28 +314,30 @@ def paged_prefill_chunk(p, cfg, blk, x, cache, block_table, start
     overwrites their page slots before reading them).
 
     Writes the chunk's KV into the pool, then attends the chunk's queries
-    against the full gathered context with absolute causal masking — so a
-    long prompt admits as a sequence of these calls interleaved with
-    decode rounds instead of one blocking batch-1 prefill.
+    against the full gathered context with absolute causal masking (and
+    the block's window) — so a long prompt admits as a sequence of these
+    calls interleaved with decode rounds instead of one blocking batch-1
+    prefill.
     """
     B, C, _ = x.shape
     positions = start + jnp.arange(C)[None]                    # (1, C)
-    q, k, v = _project_qkv(p, cfg, x, positions, blk.use_rope)
+    q, k, v = _project_qkv(p, cfg, x, positions, blk)
     k_pages = _scatter_pages(cache["k_pages"], k[0], block_table, start)
     v_pages = _scatter_pages(cache["v_pages"], v[0], block_table, start)
     max_ctx = block_table.shape[-1] * k_pages.shape[2]
     kd = _gather_pages(k_pages, block_table, max_ctx)[None]    # (1,ctx,KV,hd)
     vd = _gather_pages(v_pages, block_table, max_ctx)[None]
-    out = chunked_causal_attention(q, kd, vd, q_offset=start,
-                                   q_chunk=cfg.attn_chunk,
+    out = chunked_causal_attention(q, kd, vd, window=blk.window,
+                                   q_offset=start, q_chunk=cfg.attn_chunk,
                                    kv_chunk=cfg.attn_chunk)
     out = out.reshape(B, C, cfg.num_heads * cfg.head_dim) @ p["wo"]
     return out, {"k_pages": k_pages, "v_pages": v_pages}
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lengths
-                    ) -> jnp.ndarray:
-    """The paged flash-decode kernel call (``kernels.ops``).
+def paged_attention(q, k_pages, v_pages, block_tables, lengths,
+                    window: Optional[int] = None) -> jnp.ndarray:
+    """The paged flash-decode kernel call (``kernels.ops``); ``window``
+    keeps each lane's last ``window`` keys.
 
     Under an active ShardingContext it runs inside ``shard_map``: GSPMD
     cannot partition a Mosaic kernel.  Each shard sees the page pool as
@@ -340,6 +346,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths
     from repro.kernels import ops as kernel_ops
 
     kernel = kernel_ops.paged_flash_decode
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
     ctx = sharding.current()
     if ctx is None:
         return kernel(q, k_pages, v_pages, block_tables, lengths)
@@ -357,14 +365,15 @@ def paged_decode(p, cfg, blk, x, cache, block_tables, lengths
 
     x (B, 1, d); lengths (B,) tokens already written per lane.  Writes the
     new token's KV at position lengths[b] of each lane's block table, then
-    attends lengths[b]+1 tokens via the paged flash-decode kernel (TPU)
-    or its XLA gather twin (CPU).  Inactive lanes pass a null block table
+    attends lengths[b]+1 tokens (the last ``blk.window`` of them on a
+    windowed layer) via the paged flash-decode kernel (TPU) or its XLA
+    gather twin (CPU).  Inactive lanes pass a null block table
     (all page 0) and length 0 — their writes and reads hit the reserved
     null page and their outputs are discarded by the engine.
     """
     B = x.shape[0]
     hd = cfg.head_dim
-    q, k, v = _project_qkv(p, cfg, x, lengths[:, None], blk.use_rope)
+    q, k, v = _project_qkv(p, cfg, x, lengths[:, None], blk)
     ps = cache["k_pages"].shape[2]
     page = _page_of(block_tables, lengths[:, None], ps)[:, 0]  # (B,)
     slot = lengths % ps
@@ -373,8 +382,9 @@ def paged_decode(p, cfg, blk, x, cache, block_tables, lengths
         k[:, 0].astype(dtype))
     v_pages = cache["v_pages"].at[page, :, slot].set(
         v[:, 0].astype(dtype))
+    windowed = {} if blk.window is None else {"window": blk.window}
     out = paged_attention(q[:, 0], k_pages, v_pages, block_tables,
-                          lengths + 1)
+                          lengths + 1, **windowed)
     out = out.reshape(B, 1, cfg.num_heads * hd).astype(x.dtype)
     out = out @ p["wo"]
     return out, {"k_pages": k_pages, "v_pages": v_pages}
@@ -385,7 +395,7 @@ def decode(p, cfg, blk, x, cache) -> Tuple[jnp.ndarray, dict]:
     B = x.shape[0]
     hd = cfg.head_dim
     pos = cache["pos"]                                   # scalar int32
-    q, k, v = _project_qkv(p, cfg, x, jnp.full((B, 1), pos), blk.use_rope)
+    q, k, v = _project_qkv(p, cfg, x, jnp.full((B, 1), pos), blk)
     W = cache["k"].shape[2]
     slot = pos % W
     quant = cfg.kv_cache_dtype == "int8"

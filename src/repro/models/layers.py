@@ -12,6 +12,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # ---------------------------------------------------------------------------
 # initializers
@@ -47,18 +48,45 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
+def yarn_freqs(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies, shape (head_dim // 2,), as HF's
+    ``_compute_yarn_parameters`` computes them: the interpolated
+    frequencies (divided by ``factor``) below the correction range, the
+    original ones above it, and a linear ramp between."""
+    dim = head_dim
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extra, inter = 1.0 / pos, 1.0 / (yarn.factor * pos)
+
+    def corr_dim(rot):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rot * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp                 # 1: original, 0: interpolated
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float = 10_000.0) -> jnp.ndarray:
-    """Rotary embedding.
+               theta: float = 10_000.0, yarn=None) -> jnp.ndarray:
+    """Rotary embedding; with ``yarn`` (a ``YarnConfig``), YaRN's
+    frequencies and cos/sin scaled by its ``attention_factor``.
 
     x: (..., seq, heads, head_dim); positions: (..., seq) or (seq,).
     """
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)                     # (hd/2,)
+    freqs = (rope_freqs(head_dim, theta) if yarn is None
+             else jnp.asarray(yarn_freqs(head_dim, theta, yarn)))  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     # broadcast over the heads axis
     angles = angles[..., :, None, :]                        # (..., S, 1, hd/2)
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if yarn is not None:
+        sin, cos = sin * yarn.attention_factor, cos * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

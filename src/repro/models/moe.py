@@ -1,11 +1,18 @@
-"""Mixture-of-Experts FFN with capacity-bounded dispatch.
+"""Mixture-of-Experts FFN.
 
-Two execution paths share the same weights and (capacity) semantics:
+Three execution paths share the same weights:
 
-* ``_apply_moe_local`` — single-device reference: FIFO capacity selection
-  per expert via gather, used on CPU (smoke tests) and as the oracle.
+* ``_apply_moe_local`` — the single-device path (the serving engine's):
+  the exact top-k softmax mixture, renormalised, with no token dropped.
+  Every expert runs on every token of the call and a one-hot combine
+  keeps each token's top k, so each expert's weights are read once per
+  call.  At Mellum2's shapes on a v5e this beat the grouped alternatives
+  that read only the chosen experts (``jax.lax.ragged_dot`` and the
+  megablox ``gmm`` kernel), at 16 decode rows and at a 256-row chunk
+  alike (PERF.md, findings on Mellum2).
 
-* ``_apply_moe_sharded`` — the TPU adaptation (see DESIGN.md).  Key
+* ``_apply_moe_sharded`` — the multi-chip adaptation, capacity-bounded
+  (see DESIGN.md).  Key
   observation: with activations sharded over the data axis and *replicated*
   over the model axis, expert parallelism needs NO all-to-all: every model
   shard already holds the tokens, so shard j simply computes its owned
@@ -37,18 +44,20 @@ from repro.models.layers import dense_init
 
 def init_moe(key, cfg, dtype) -> dict:
     m = cfg.moe
-    d, f, E = cfg.d_model, cfg.d_ff, m.num_experts
+    d, f, E = cfg.d_model, cfg.d_expert, m.num_experts
     ks = jax.random.split(key, 4)
+
+    def experts(k, fan_in, fan_out):      # one key per expert
+        return jax.vmap(lambda ke: dense_init(ke, fan_in, fan_out, dtype))(
+            jax.random.split(k, E))
+
     p = {
         "router": dense_init(ks[0], d, E, jnp.float32),
-        "we_up": jnp.stack([dense_init(k, d, f, dtype)
-                            for k in jax.random.split(ks[1], E)]),
-        "we_down": jnp.stack([dense_init(k, f, d, dtype)
-                              for k in jax.random.split(ks[2], E)]),
+        "we_up": experts(ks[1], d, f),
+        "we_down": experts(ks[2], f, d),
     }
     if cfg.gated_ffn:
-        p["we_gate"] = jnp.stack([dense_init(k, d, f, dtype)
-                                  for k in jax.random.split(ks[3], E)])
+        p["we_gate"] = experts(ks[3], d, f)
     return p
 
 
@@ -58,7 +67,8 @@ def init_moe(key, cfg, dtype) -> dict:
 
 
 def _route(router_w, cfg, xf):
-    """xf (T, d) -> (gate_dense (T, E) f32, aux scalar)."""
+    """xf (T, d) -> (gate_dense (T, E) f32, aux scalar, tokens routed to
+    each expert (E,) int32)."""
     m = cfg.moe
     E, K = m.num_experts, m.top_k
     logits = xf.astype(jnp.float32) @ router_w
@@ -72,7 +82,13 @@ def _route(router_w, cfg, xf):
     density = jnp.mean(onehot[:, 0], axis=0)
     density_proxy = jnp.mean(probs, axis=0)
     aux = jnp.sum(density * density_proxy) * E * m.router_aux_weight
-    return w_dense, aux
+    return w_dense, aux, expert_counts(idx, E)
+
+
+def expert_counts(idx, num_experts: int) -> jnp.ndarray:
+    """Tokens routed to each expert, (E,) int32, from ids (T, K)."""
+    return jnp.bincount(idx.reshape(-1), length=num_experts
+                        ).astype(jnp.int32)
 
 
 def _expert_ffn(xb, wu, wd, wg):
@@ -106,19 +122,21 @@ def _one_expert(xf, w_col, wu, wd, wg, C: int):
 
 
 # ---------------------------------------------------------------------------
-# single-device reference path
+# single-device path: exact, dropless
 # ---------------------------------------------------------------------------
 
 
-def _apply_moe_local(p, cfg, xf) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    w_dense, aux = _route(p["router"], cfg, xf)
-    C = _capacity(cfg, xf.shape[0])
-    out = jnp.zeros_like(xf)
-    for e in range(cfg.moe.num_experts):
-        wg = p["we_gate"][e] if "we_gate" in p else None
-        out = out + _one_expert(xf, w_dense[:, e], p["we_up"][e],
-                                p["we_down"][e], wg, C)
-    return out, aux
+def _apply_moe_local(p, cfg, xf):
+    """Returns (out (T, d), aux, tokens per expert (E,))."""
+    w, aux, counts = _route(p["router"], cfg, xf)
+    up = jnp.einsum("td,edf->etf", xf, p["we_up"])
+    if "we_gate" in p:
+        h = jax.nn.silu(jnp.einsum("td,edf->etf", xf, p["we_gate"])) * up
+    else:
+        h = jax.nn.gelu(up)
+    y = jnp.einsum("etf,efd->etd", h, p["we_down"])
+    out = jnp.einsum("te,etd->td", w, y.astype(jnp.float32))
+    return out.astype(xf.dtype), aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +149,7 @@ def _layout_dims(cfg, M: int):
     e_par = math.gcd(E, M)
     f_par = M // e_par
     r = E // e_par                      # experts per expert-parallel shard
-    f_lp = cfg.d_ff // f_par
+    f_lp = cfg.d_expert // f_par
     return e_par, f_par, r, f_lp
 
 
@@ -166,7 +184,7 @@ def layout_cols_inv(w, cfg, M):
     perm = tuple(range(len(lead))) + tuple(
         len(lead) + i for i in (0, 2, 3, 1, 4))
     return w.transpose(perm).reshape(*lead, cfg.moe.num_experts, d,
-                                     cfg.d_ff)
+                                     cfg.d_expert)
 
 
 def layout_rows_inv(w, cfg, M):
@@ -177,7 +195,7 @@ def layout_rows_inv(w, cfg, M):
     perm = tuple(range(len(lead))) + tuple(
         len(lead) + i for i in (0, 2, 1, 3, 4))
     return w.transpose(perm).reshape(*lead, cfg.moe.num_experts,
-                                     cfg.d_ff, d)
+                                     cfg.d_expert, d)
 
 
 def prepare_tree(params, cfg, M: int):
@@ -219,7 +237,8 @@ def unprepare_grads(grads, cfg, M: int):
     return walk(grads)
 
 
-def _apply_moe_sharded(p, cfg, xf, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _apply_moe_sharded(p, cfg, xf, ctx) -> Tuple[jnp.ndarray, jnp.ndarray,
+                                                 jnp.ndarray]:
     mesh = ctx.mesh
     M = mesh.shape["model"]
     e_par, f_par, r, f_lp = _layout_dims(cfg, M)
@@ -234,12 +253,13 @@ def _apply_moe_sharded(p, cfg, xf, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
         wd = layout_rows(p["we_down"], cfg, M)
 
     dp = ctx.rules.get("batch")
+    dp_axes = dp if isinstance(dp, tuple) else ((dp,) if dp else ())
     tok_spec = P(dp, None)
     gated = wg is not None
 
     def body(x_loc, router_w, wu_l, wd_l, wg_l):
         # x_loc (T_loc, d); w*_l (1, r, ...) local expert slices
-        w_dense, aux = _route(router_w, cfg, x_loc)
+        w_dense, aux, counts = _route(router_w, cfg, x_loc)
         C = _capacity(cfg, x_loc.shape[0])
         j = jax.lax.axis_index("model")
         my_e_par = j // f_par
@@ -256,7 +276,9 @@ def _apply_moe_sharded(p, cfg, xf, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
         # aux varies across token shards: globally mean it so the returned
         # scalar is replicated (out_specs P()).
         aux = jax.lax.pmean(aux, tuple(mesh.axis_names))
-        return out, aux
+        if dp_axes:                     # this shard's tokens -> all tokens
+            counts = jax.lax.psum(counts, dp_axes)
+        return out, aux, counts
 
     in_specs = (tok_spec, P(None, None), P("model"), P("model"))
     args = [xf, p["router"], wu, wd]
@@ -270,12 +292,13 @@ def _apply_moe_sharded(p, cfg, xf, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
     fn = jax.shard_map(
         body if gated else (lambda x, rw, a, b, c: body(x, rw, a, b, None)),
         mesh=mesh, in_specs=in_specs,
-        out_specs=(tok_spec, P()), check_vma=False)
-    out, aux = fn(*args)
-    return out, aux[()] if aux.ndim else aux
+        out_specs=(tok_spec, P(), P()), check_vma=False)
+    out, aux, counts = fn(*args)
+    return out, aux[()] if aux.ndim else aux, counts
 
 
 def _apply_moe_stationary(p, cfg, xf, ctx) -> Tuple[jnp.ndarray,
+                                                    jnp.ndarray,
                                                     jnp.ndarray]:
     """Weights-stationary serving path (decode-sized token counts).
 
@@ -324,7 +347,7 @@ def _apply_moe_stationary(p, cfg, xf, ctx) -> Tuple[jnp.ndarray,
         for ax in reversed(dp_axes):
             x_all = jax.lax.all_gather(x_all, ax, axis=0, tiled=True)
         T = x_all.shape[0]
-        w_dense, aux = _route(router_w, cfg, x_all)
+        w_dense, aux, counts = _route(router_w, cfg, x_all)
         C = _capacity(cfg, T)
         j = jax.lax.axis_index("model")
         i = jax.lax.axis_index("data")
@@ -367,7 +390,7 @@ def _apply_moe_stationary(p, cfg, xf, ctx) -> Tuple[jnp.ndarray,
         out_loc = jax.lax.dynamic_slice_in_dim(out_full, start, T_loc,
                                                axis=0)
         aux = jax.lax.pmean(aux, tuple(mesh.axis_names))
-        return out_loc.astype(x_loc.dtype), aux
+        return out_loc.astype(x_loc.dtype), aux, counts
 
     in_specs = (tok_spec, P(None, None), P("model", "data"),
                 P("model", "data"),
@@ -376,22 +399,25 @@ def _apply_moe_stationary(p, cfg, xf, ctx) -> Tuple[jnp.ndarray,
             wg if gated else jnp.zeros((M, D, 1, 1, 1), xf.dtype)]
     fn = jax.shard_map(
         body if gated else (lambda x, rw, a, b, c: body(x, rw, a, b, None)),
-        mesh=mesh, in_specs=in_specs, out_specs=(tok_spec, P()),
+        mesh=mesh, in_specs=in_specs, out_specs=(tok_spec, P(), P()),
         check_vma=False)
-    out, aux = fn(*args)
-    return out, aux[()] if aux.ndim else aux
+    out, aux, counts = fn(*args)
+    return out, aux[()] if aux.ndim else aux, counts
 
 
-def apply_moe(p: dict, cfg, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x (B, S, d) -> (out (B, S, d), aux_loss scalar)."""
+def apply_moe(p: dict, cfg, x: jnp.ndarray
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """x (B, S, d) -> (out (B, S, d), aux_loss scalar, tokens routed to
+    each expert (E,) int32)."""
     B, S, d = x.shape
     xf = x.reshape(B * S, d)
     ctx = sharding.current()
     if ctx is None or "model" not in ctx.mesh.axis_names:
-        out, aux = _apply_moe_local(p, cfg, xf)
+        apply = _apply_moe_local
     elif (cfg.moe_stationary_serve and "data" in ctx.mesh.axis_names
-          and B * S <= cfg.moe_stationary_max_tokens):
-        out, aux = _apply_moe_stationary(p, cfg, xf, ctx)
+            and B * S <= cfg.moe_stationary_max_tokens):
+        apply = partial(_apply_moe_stationary, ctx=ctx)
     else:
-        out, aux = _apply_moe_sharded(p, cfg, xf, ctx)
-    return out.reshape(B, S, d), aux
+        apply = partial(_apply_moe_sharded, ctx=ctx)
+    out, aux, counts = apply(p, cfg, xf)
+    return out.reshape(B, S, d), aux, counts

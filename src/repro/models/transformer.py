@@ -231,7 +231,7 @@ def init_paged_states(cfg, num_pages: int, page_size: int,
 
 def _apply_block(cfg, blk, p, x, positions, state, mode, max_len=None,
                  paged=None):
-    """Returns (x_out, new_state, aux)."""
+    """Returns (x_out, new_state, aux, tokens per expert (E,) or None)."""
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     new_state = state
     if mode in ("paged_prefill", "paged_decode") and blk.mixer != "attn":
@@ -274,6 +274,7 @@ def _apply_block(cfg, blk, p, x, positions, state, mode, max_len=None,
         raise ValueError(blk.mixer)
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     if blk.ffn != "none":
         h2 = rmsnorm(p["norm2"], x, cfg.norm_eps)
         if blk.ffn == "dense":
@@ -281,10 +282,10 @@ def _apply_block(cfg, blk, p, x, positions, state, mode, max_len=None,
             dp = ctx.sharding("batch", None, "ff") if ctx else None
             y = apply_mlp(p["ffn"], h2, dp_spec=dp)
         else:
-            y, aux = moe.apply_moe(p["ffn"], cfg, h2)
+            y, aux, counts = moe.apply_moe(p["ffn"], cfg, h2)
         x = x + y
     x = sharding.act(x, "batch", "seq", None)
-    return x, new_state, aux
+    return x, new_state, aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -330,35 +331,47 @@ def apply_head(params, cfg, x) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def _stack_counts(counts):
+    """Per-layer expert counts, in layer order, as one (L_moe, E) array
+    (None without expert layers)."""
+    return jnp.concatenate(counts) if counts else None
+
+
 def _scan_layers(cfg, layers, x, positions, states, mode, max_len=None,
                  paged=None):
-    """Grouped scan over layers.  Returns (x, new_states, aux_sum)."""
+    """Grouped scan over layers.  Returns (x, new_states, aux_sum,
+    expert counts (L_moe, E) or None)."""
     pattern = cfg.layer_pattern()
     P = len(cfg.pattern)
     n_groups, rem = _layer_layout(cfg)
     aux_total = jnp.zeros((), jnp.float32)
+    counts = []
 
     if n_groups > 0:
         def group_body(carry, xs):
             xc, aux = carry
             gp, gs = xs            # per-position lists stacked over groups
-            new_gs = []
+            new_gs, cs = [], []
             for j in range(P):
-                xc, ns, a = _apply_block(cfg, pattern[j], gp[j], xc,
-                                         positions,
-                                         gs[j] if gs is not None else None,
-                                         mode, max_len=max_len, paged=paged)
+                xc, ns, a, c = _apply_block(
+                    cfg, pattern[j], gp[j], xc, positions,
+                    gs[j] if gs is not None else None, mode,
+                    max_len=max_len, paged=paged)
                 new_gs.append(ns)
                 aux = aux + a
-            return (xc, aux), new_gs
+                if c is not None:
+                    cs.append(c)
+            return (xc, aux), (new_gs, cs)
 
         body = group_body
         if cfg.remat and mode == "train":
             body = jax.checkpoint(group_body,
                                   policy=jax.checkpoint_policies.nothing_saveable)
         scan_states = states["scan"] if states is not None else None
-        (x, aux_total), new_scan_states = jax.lax.scan(
+        (x, aux_total), (new_scan_states, cs) = jax.lax.scan(
             body, (x, aux_total), (layers["scan"], scan_states))
+        if cs:                 # (groups, positions, E): layer g * P + j
+            counts.append(jnp.stack(cs, axis=1).reshape(-1, cs[0].shape[-1]))
     else:
         new_scan_states = states["scan"] if states is not None else []
 
@@ -372,27 +385,33 @@ def _scan_layers(cfg, layers, x, positions, states, mode, max_len=None,
         if cfg.remat and mode == "train":
             blk_fn = jax.checkpoint(
                 blk_fn, policy=jax.checkpoint_policies.nothing_saveable)
-        x, ns, a = blk_fn(layers["rem"][j], x, st)
+        x, ns, a, c = blk_fn(layers["rem"][j], x, st)
         new_rem.append(ns)
         aux_total = aux_total + a
+        if c is not None:
+            counts.append(c[None])
     new_states = None
     if mode != "train":
         new_states = {"scan": new_scan_states, "rem": new_rem}
-    return x, new_states, aux_total
+    return x, new_states, aux_total, _stack_counts(counts)
 
 
 def _flat_layers(cfg, layers, x, positions, states, mode, max_len=None,
                  paged=None):
     pattern = cfg.layer_pattern()
     aux_total = jnp.zeros((), jnp.float32)
-    new_states = []
+    new_states, counts = [], []
     for i, blk in enumerate(pattern):
         st = states["flat"][i] if states is not None else None
-        x, ns, a = _apply_block(cfg, blk, layers["flat"][i], x, positions,
-                                st, mode, max_len=max_len, paged=paged)
+        x, ns, a, c = _apply_block(cfg, blk, layers["flat"][i], x,
+                                   positions, st, mode, max_len=max_len,
+                                   paged=paged)
         new_states.append(ns)
         aux_total = aux_total + a
-    return x, ({"flat": new_states} if mode != "train" else None), aux_total
+        if c is not None:
+            counts.append(c[None])
+    return (x, ({"flat": new_states} if mode != "train" else None),
+            aux_total, _stack_counts(counts))
 
 
 def forward(params, cfg, inputs: dict, mode: str = "train",
@@ -407,6 +426,9 @@ def forward(params, cfg, inputs: dict, mode: str = "train",
                     + paged states               -> {chunk_logits, states}
     paged_decode  : inputs {tokens (B,1), block_tables (B,W), lengths (B,)}
                     + paged states               -> {logits, states}
+
+    With expert layers every mode also returns ``expert_tokens``, the
+    tokens routed to each expert of each such layer, (L_moe, E) int32.
     """
     paged = None
     if mode == "decode":
@@ -435,10 +457,13 @@ def forward(params, cfg, inputs: dict, mode: str = "train",
         positions = jnp.arange(S)
 
     run = _scan_layers if cfg.scan_layers else _flat_layers
-    x, new_states, aux = run(cfg, params["layers"], x, positions, states,
-                             mode, max_len=max_len, paged=paged)
+    x, new_states, aux, counts = run(cfg, params["layers"], x, positions,
+                                     states, mode, max_len=max_len,
+                                     paged=paged)
 
     out: Dict[str, Any] = {"aux": aux}
+    if counts is not None:
+        out["expert_tokens"] = counts
     if mode == "train":
         out["hidden"] = x
     elif mode == "prefill":

@@ -161,17 +161,29 @@ def paged_decode_step(cfg, num_pages: int, page_size: int, sample: bool,
                         donate_argnums=2))
 
 
+def _fork(axis: int, src, dst) -> Callable:
+    """Copy page ``src`` onto page ``dst`` along the page axis ``axis``."""
+    def one(a):
+        page = jax.lax.dynamic_index_in_dim(a, src, axis, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(a, page, dst, axis)
+    return one
+
+
 def page_copy_step(cfg, num_pages: int, page_size: int, mesh=None) -> Callable:
     """Jitted copy-on-write page fork: every per-layer K/V pool copies
     physical page ``src`` onto page ``dst`` in place (donated states, and
     src/dst are traced scalars so one compile covers every fork).  Used
     when a lane's prompt diverges MID-block from a cached prefix: the
     matched head of the cached page is duplicated so the lane can
-    overwrite its private tail without corrupting the shared original."""
+    overwrite its private tail without corrupting the shared original.
+
+    The page axis is axis 1 of the pools under ``states["scan"]`` (their
+    axis 0 stacks the scanned layers) and axis 0 of every other pool."""
     def build():
         def copy(states, src, dst):
-            return jax.tree_util.tree_map(
-                lambda a: a.at[dst].set(a[src]), states)
+            return {part: jax.tree_util.tree_map(
+                        _fork(1 if part == "scan" else 0, src, dst), pools)
+                    for part, pools in states.items()}
 
         return jax.jit(copy, donate_argnums=0)
 

@@ -134,6 +134,7 @@ class _Pending:
         dataclasses.field(default_factory=list)
     decode: Optional[Tuple[jax.Array,
                            List[Tuple[int, Request, int, bool]]]] = None
+    expert_tokens: Optional[jax.Array] = None   # (L_moe, E) of the round
 
 
 class ServeEngine:
@@ -186,6 +187,12 @@ class ServeEngine:
         self.prefix_hits = 0
         self.prefix_lookups = 0
         self.cow_forks = 0
+        # routing counters of the decode rounds (expert layers only): the
+        # tokens each expert of each layer took, summed, and the distinct
+        # experts a round's layer reached, summed over rounds
+        self.expert_tokens: Optional[np.ndarray] = None
+        self.expert_rounds = 0
+        self._experts_hit_sum = 0.0
 
         self.paged = paged_supported(cfg) if paged is None else bool(paged)
         if self.paged:
@@ -309,10 +316,10 @@ class ServeEngine:
         if self._pending is None:
             return []
         with tracing.span("repro.engine.collect",
-                          engine=getattr(self, "engine_id", None)):
-            return self._collect()
+                          engine=getattr(self, "engine_id", None)) as sp:
+            return self._collect(sp)
 
-    def _collect(self) -> List[Request]:
+    def _collect(self, sp) -> List[Request]:
         pend, self._pending = self._pending, None
         finished: List[Request] = []
 
@@ -327,6 +334,8 @@ class ServeEngine:
             with tracing.span("repro.engine.sync"):
                 tok_np = np.asarray(tok_all)       # blocks until ready
             self._note_round(self._round_t0, len(entries))
+            if pend.expert_tokens is not None:
+                self._note_experts(np.asarray(pend.expert_tokens), sp)
 
         # deferred prefill first-tokens (ready by now: they were enqueued
         # before the decode round)
@@ -444,8 +453,9 @@ class ServeEngine:
             self._round_t0 = self._clock()
             with tracing.span("repro.engine.decode", lanes=len(active),
                               lengths=context), self._sharded():
-                _, tok_all, self._paged_states = self._paged_decode(
-                    self.params, batch, self._paged_states, key)
+                _, tok_all, self._paged_states, *experts = \
+                    self._paged_decode(self.params, batch,
+                                       self._paged_states, key)
             entries = []
             for i in active:
                 lane = self._lanes[i]
@@ -460,6 +470,7 @@ class ServeEngine:
                 if fin:
                     self._free_lane(i)
             pend.decode = (tok_all, entries)
+            pend.expert_tokens = experts[0] if experts else None
         return pend
 
     def _admit_paged(self) -> int:
@@ -624,6 +635,9 @@ class ServeEngine:
         self.prefix_hits = 0
         self.prefix_lookups = 0
         self.cow_forks = 0
+        self.expert_tokens = None
+        self.expert_rounds = 0
+        self._experts_hit_sum = 0.0
         if self.paged:
             for i, lane in enumerate(self._lanes):
                 if lane is not None:
@@ -873,6 +887,24 @@ class ServeEngine:
         dt = (self._clock() - t0) / active
         self._ewma_tok_s = (0.7 * self._ewma_tok_s + 0.3 * dt
                             if self._ewma_tok_s else dt)
+
+    def _note_experts(self, counts: np.ndarray, sp) -> None:
+        """Add one decode round's tokens per (layer, expert); the span
+        gets the round's mean experts reached per layer and its largest
+        expert load.  Every lane of the round is routed, idle ones too."""
+        hit = float((counts > 0).sum(-1).mean())
+        self.expert_tokens = (counts.astype(np.int64)
+                              if self.expert_tokens is None
+                              else self.expert_tokens + counts)
+        self.expert_rounds += 1
+        self._experts_hit_sum += hit
+        sp.set(experts_hit=hit, expert_load_max=int(counts.max()))
+
+    @property
+    def experts_hit(self) -> float:
+        """Mean, over decode rounds and expert layers, of the distinct
+        experts a round's tokens reached (0.0 before any round)."""
+        return self._experts_hit_sum / max(self.expert_rounds, 1)
 
     def _note_inflight(self, n: int) -> None:
         self.peak_inflight = max(self.peak_inflight, n)

@@ -378,13 +378,13 @@ class PrefixCache:
 def paged_supported(cfg) -> bool:
     """Whether a config can be served from a shared page pool.
 
-    Requires every block to be full (unwindowed) attention with a
-    model-dtype cache; recurrent mixers, ring-buffer windows and int8
-    caches keep the dense per-slot path.
+    Requires every block to be attention (full or windowed) with a
+    model-dtype cache; recurrent mixers and int8 caches keep the dense
+    per-slot path.  A windowed layer keeps every page of its lanes and
+    reads only the pages in its window.
     """
     if cfg.kv_cache_dtype == "int8":
         return False
     if getattr(cfg, "vision_patches", 0):
         return False
-    return all(b.mixer == "attn" and b.window is None
-               for b in cfg.layer_pattern())
+    return all(b.mixer == "attn" for b in cfg.layer_pattern())

@@ -144,7 +144,8 @@ def make_paged_decode_step(cfg, sample: bool = False,
 
     batch = {tokens (B, 1), block_tables (B, W), lengths (B,)}; inactive
     lanes carry all-null tables and length 0, and their tokens are
-    ignored by the engine.
+    ignored by the engine.  Returns (logits, tokens, new page pools),
+    and with expert layers also the round's tokens per expert (L_moe, E).
     """
     def paged_decode_step(params, batch, states, rng=None):
         params = _maybe_hoist(cfg, params)
@@ -154,7 +155,10 @@ def make_paged_decode_step(cfg, sample: bool = False,
             tok = jax.random.categorical(rng, logits / temperature, axis=-1)
         else:
             tok = jnp.argmax(logits, axis=-1)
-        return logits, tok.astype(jnp.int32), out["states"]
+        ret = (logits, tok.astype(jnp.int32), out["states"])
+        if "expert_tokens" in out:
+            ret += (out["expert_tokens"],)
+        return ret
 
     return paged_decode_step
 

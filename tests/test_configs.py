@@ -15,6 +15,8 @@ EXPECTED = {
     "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
     "llava-next-mistral-7b": (32, 4096, 32, 8, 14336, 32000),
     "qwen2-1.5b": (28, 1536, 12, 2, 8960, 151936),
+    "mellum2-12b-a2.5b": (28, 2304, 32, 4, 7168, 98304),
+    "mellum2-12b-a2.5b-d12": (12, 2304, 32, 4, 7168, 98304),
 }
 
 FAMILY = {
@@ -22,13 +24,14 @@ FAMILY = {
     "minitron-8b": "dense", "starcoder2-7b": "dense",
     "mixtral-8x22b": "moe", "xlstm-350m": "ssm",
     "recurrentgemma-9b": "hybrid", "llava-next-mistral-7b": "vlm",
-    "qwen2-1.5b": "dense",
+    "qwen2-1.5b": "dense", "mellum2-12b-a2.5b": "moe",
+    "mellum2-12b-a2.5b-d12": "moe",
 }
 
 
 def test_registry_complete():
     assert sorted(ARCH_IDS) == sorted(EXPECTED)
-    assert len(all_configs()) == 10
+    assert len(all_configs()) == 12
 
 
 @pytest.mark.parametrize("arch", sorted(EXPECTED))
@@ -56,6 +59,10 @@ def test_family_traits():
     assert not get_config("musicgen-large").use_rope
     assert get_config("llava-next-mistral-7b").vision_patches == 576
     assert get_config("recurrentgemma-9b").local_window == 2048
+    mellum = get_config("mellum2-12b-a2.5b")
+    assert (mellum.moe.num_experts, mellum.moe.top_k) == (64, 8)
+    assert mellum.d_expert == 896 and mellum.d_ff == 7168
+    assert mellum.yarn.factor == 16.0
 
 
 def test_patterns_expand():
@@ -72,6 +79,11 @@ def test_patterns_expand():
     rec = [b for b in rg if b.mixer == "rglru"]
     assert len(rec) > len(attn)
     assert all(b.window == 2048 for b in attn)
+    # Mellum2: (sliding, sliding, sliding, full); YaRN on the full layers
+    pat = get_config("mellum2-12b-a2.5b-d12").layer_pattern()
+    assert [b.window for b in pat] == [1024, 1024, 1024, None] * 3
+    assert [b.yarn for b in pat] == [False, False, False, True] * 3
+    assert all(b.ffn == "moe" for b in pat)
 
 
 def test_subquadratic_flags():
@@ -98,7 +110,8 @@ def test_param_counts_plausible():
     approx = {"dbrx-132b": 132e9, "mixtral-8x22b": 141e9,
               "qwen2-1.5b": 1.5e9, "starcoder2-3b": 3e9,
               "starcoder2-7b": 7e9, "minitron-8b": 8e9,
-              "recurrentgemma-9b": 9e9, "xlstm-350m": 350e6}
+              "recurrentgemma-9b": 9e9, "xlstm-350m": 350e6,
+              "mellum2-12b-a2.5b": 12e9}
     for arch, n in approx.items():
         got = get_config(arch).param_count()
         assert 0.5 * n < got < 2.2 * n, (arch, got, n)
@@ -108,5 +121,7 @@ def test_active_params_less_for_moe():
     for arch in ("dbrx-132b", "mixtral-8x22b"):
         cfg = get_config(arch)
         assert cfg.active_param_count() < cfg.param_count()
+    cfg = get_config("mellum2-12b-a2.5b")      # 12B total, 2.5B active
+    assert 2.2e9 < cfg.active_param_count() < 2.8e9
     cfg = get_config("qwen2-1.5b")
     assert cfg.active_param_count() == cfg.param_count()

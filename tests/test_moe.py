@@ -28,7 +28,7 @@ def test_output_shape_and_aux():
     cfg = _cfg()
     p = moelib.init_moe(KEY, cfg, jnp.float32)
     x = jax.random.normal(KEY, (2, 16, cfg.d_model))
-    out, aux = moelib.apply_moe(p, cfg, x)
+    out, aux, _ = moelib.apply_moe(p, cfg, x)
     assert out.shape == x.shape
     assert float(aux) > 0  # load-balance loss is positive
 
@@ -39,7 +39,7 @@ def test_topk_only_active_experts_contribute():
     cfg = _cfg(E=4, K=1, cf=4.0)
     p = moelib.init_moe(KEY, cfg, jnp.float32)
     x = jax.random.normal(jax.random.key(1), (1, 8, cfg.d_model))
-    out1, _ = moelib.apply_moe(p, cfg, x)
+    out1, *_ = moelib.apply_moe(p, cfg, x)
     # find each token's chosen expert, then zero a never-chosen expert
     xf = x.reshape(-1, cfg.d_model)
     probs = jax.nn.softmax(xf @ p["router"], axis=-1)
@@ -52,25 +52,59 @@ def test_topk_only_active_experts_contribute():
     for k in ("we_up", "we_down", "we_gate"):
         if k in p2:
             p2[k] = p2[k].at[e].set(0.0)
-    out2, _ = moelib.apply_moe(p2, cfg, x)
+    out2, *_ = moelib.apply_moe(p2, cfg, x)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                atol=1e-6)
 
 
+def _mixture(p, cfg, x):
+    """The exact top-k mixture, token by token and expert by expert."""
+    xf = x.reshape(-1, cfg.d_model)
+    gate, idx = jax.lax.top_k(jax.nn.softmax(xf @ p["router"], -1),
+                              cfg.moe.top_k)
+    gate = gate / gate.sum(-1, keepdims=True)
+    out = []
+    for t in range(xf.shape[0]):
+        y = 0.0
+        for g, e in zip(gate[t], idx[t]):
+            h = (jax.nn.silu(xf[t] @ p["we_gate"][e])
+                 * (xf[t] @ p["we_up"][e]))
+            y = y + g * (h @ p["we_down"][e])
+        out.append(y)
+    return jnp.stack(out).reshape(x.shape)
+
+
 def test_capacity_drops_tokens():
-    """With capacity factor ~0, outputs must (mostly) vanish."""
+    """The single-device path drops no token, whatever the capacity
+    factor: at ~0 (a capacity of one token per expert) it still gives
+    every token its exact top-k mixture, and counts every routed token."""
     cfg = _cfg(E=4, K=2, cf=1e-6)
     p = moelib.init_moe(KEY, cfg, jnp.float32)
     x = jax.random.normal(KEY, (2, 32, cfg.d_model))
-    out, _ = moelib.apply_moe(p, cfg, x)
-    cfg_big = dataclasses.replace(
-        cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=2.0))
-    out_big, _ = moelib.apply_moe(p, cfg_big, x)
-    # capacity C=1 keeps at most 4 tokens' worth of outputs
-    dropped = float((jnp.abs(out).sum(-1) == 0).mean())
-    kept_big = float((jnp.abs(out_big).sum(-1) > 0).mean())
-    assert dropped > 0.5
-    assert kept_big > 0.9
+    out, _, counts = moelib.apply_moe(p, cfg, x)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_mixture(p, cfg, x)),
+                               atol=1e-5, rtol=1e-5)
+    assert int(counts.sum()) == 2 * 32 * 2 and counts.shape == (4,)
+
+
+@pytest.mark.parametrize("skew", [False, True])
+def test_dropless_when_most_tokens_choose_one_expert(skew):
+    """64 experts, top 8, 16 decode rows: a capacity bound of
+    ceil(1.25 T K / E) = 3 tokens per expert would drop tokens in most
+    rounds, and every one on the skewed batch (identical rows)."""
+    cfg = _cfg(E=64, K=8, cf=1.25, f=32)
+    p = moelib.init_moe(KEY, cfg, jnp.float32)
+    x = jax.random.normal(jax.random.key(2), (16, 1, cfg.d_model))
+    if skew:
+        x = jnp.broadcast_to(x[:1], x.shape)
+    out, _, counts = moelib.apply_moe(p, cfg, x)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_mixture(p, cfg, x)),
+                               atol=1e-5, rtol=1e-5)
+    if skew:
+        assert int(counts.max()) == 16
+    assert int(counts.sum()) == 16 * 8
 
 
 def test_moe_gradients_flow():
@@ -79,7 +113,7 @@ def test_moe_gradients_flow():
     x = jax.random.normal(KEY, (1, 16, cfg.d_model))
 
     def loss(p):
-        out, aux = moelib.apply_moe(p, cfg, x)
+        out, aux, _ = moelib.apply_moe(p, cfg, x)
         return (out ** 2).mean() + aux
 
     g = jax.grad(loss)(p)
@@ -100,16 +134,18 @@ _SUBPROC = textwrap.dedent("""
         moe=MoEConfig(num_experts=4, top_k=2, capacity_factor=2.0))
     p = moelib.init_moe(jax.random.key(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.key(1), (2, 32, cfg.d_model))
-    ref, _ = moelib.apply_moe(p, cfg, x)
+    ref, _, ref_counts = moelib.apply_moe(p, cfg, x)
     worst = 0.0
     for shape in [(2, 4), (1, 8), (4, 2), (8, 1)]:
         mesh = make_production_mesh(shape=shape, axes=("data", "model"))
         ctx = shlib.ShardingContext(mesh)
         with mesh:
             with shlib.use(ctx):
-                out, _ = jax.jit(
+                out, _, counts = jax.jit(
                     lambda p, x: moelib.apply_moe(p, cfg, x))(p, x)
         worst = max(worst, float(jnp.max(jnp.abs(out - ref))))
+        # the routing counters come from the shards' own routing
+        assert (counts == ref_counts).all(), (shape, counts, ref_counts)
     print("WORST", worst)
     assert worst < 1e-4, worst
 """)

@@ -27,12 +27,14 @@ KEY = jax.random.key(0)
 _MODE = os.environ.get("REPRO_PAGED_TEST_MODE", "interpret")
 
 
-def _kernel_paged_decode(q, kp, vp, tbl, lens, pages_per_block=None):
+def _kernel_paged_decode(q, kp, vp, tbl, lens, pages_per_block=None,
+                         window=None):
     if _MODE == "default":
         from repro.kernels import ops
-        return ops.paged_flash_decode(q, kp, vp, tbl, lens, interpret=None)
+        return ops.paged_flash_decode(q, kp, vp, tbl, lens, interpret=None,
+                                      window=window)
     return paged_flash_decode(q, kp, vp, tbl, lens, interpret=True,
-                              pages_per_block=pages_per_block)
+                              pages_per_block=pages_per_block, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -115,6 +117,50 @@ def test_paged_kernel_matches_dense_ref_ragged_scrambled(case):
     np.testing.assert_allclose(np.asarray(ora), want, atol=1e-3, rtol=1e-3)
 
 
+# (window, pages_per_block, lengths): Mellum2's grouping (KV 4, G 8) on
+# 8-token pages; lanes shorter than, equal to and past the window, a
+# window that starts mid-page and one that starts on a page boundary
+_WINDOW_CASES = {
+    "mid-page": (20, 2, [0, 1, 19, 20, 21, 57, 96]),
+    "page-aligned": (16, 2, [16, 17, 40, 96, 0, 3, 64]),
+    "one-page-blocks": (20, 1, [57, 1, 96, 33, 0, 20, 8]),
+}
+
+
+@pytest.mark.parametrize("case", list(_WINDOW_CASES))
+def test_windowed_paged_kernel_matches_ref_with_start_offset(case):
+    """A windowed lane attends its last ``window`` keys: the kernel
+    against ``paged_decode_ref(window=)`` and the dense oracle.  Pages
+    wholly before the window hold NaN for the kernel: it must never copy
+    them in (the XLA oracle gathers every page, so it gets them clean)."""
+    window, ppb, lengths = _WINDOW_CASES[case]
+    B, KV, H, hd, ps, npages = len(lengths), 4, 32, 32, 8, 12
+    q, kp, vp, tbl, lens, kd, vd = _paged_case(B, KV, H, hd, ps, npages,
+                                               lengths, seed=4)
+    want = np.asarray(ref.decode_ref(q, kd, vd, lens, window))
+    tables = np.asarray(tbl)
+    for b, ln in enumerate(lengths):
+        before = tables[b, :max(ln - window, 0) // ps]
+        if _MODE == "interpret":
+            kp = kp.at[before].set(jnp.nan)
+            vp = vp.at[before].set(jnp.nan)
+    got = np.asarray(_kernel_paged_decode(q, kp, vp, tbl, lens,
+                                          pages_per_block=ppb,
+                                          window=window))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=1e-3, rtol=1e-3)
+    if _MODE == "interpret":
+        assert not got[~live].any()
+    clean = ref.paged_decode_ref(q, *_paged_case(B, KV, H, hd, ps, npages,
+                                                 lengths, seed=4)[1:5],
+                                 window=window)
+    np.testing.assert_allclose(np.asarray(clean), want, atol=1e-5,
+                               rtol=1e-5)
+    full = np.asarray(ref.decode_ref(q, kd, vd, lens))
+    far = np.asarray(lengths) > window
+    assert not np.allclose(full[far], want[far], atol=1e-3)
+
+
 @pytest.mark.parametrize("fill", ["null-page", "out-of-range"])
 def test_paged_kernel_ignores_unmapped_table_entries(fill):
     """Entries past ceil(length/ps) may point anywhere (the null page, or
@@ -184,6 +230,10 @@ def test_page_pool_pages_needed_and_block_table():
 def test_paged_supported_gating():
     assert paged_supported(reduced(get_config("qwen2-1.5b")))
     assert not paged_supported(reduced(get_config("xlstm-350m")))
+    # windowed attention pages too; recurrent mixers beside it do not
+    assert paged_supported(get_config("mellum2-12b-a2.5b-d12"))
+    assert paged_supported(reduced(get_config("mixtral-8x22b")))
+    assert not paged_supported(reduced(get_config("recurrentgemma-9b")))
 
 
 # ---------------------------------------------------------------------------

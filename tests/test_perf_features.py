@@ -81,16 +81,18 @@ _SUBPROC = textwrap.dedent("""
         moe_stationary_serve=True, moe_stationary_max_tokens=4096)
     p = moelib.init_moe(jax.random.key(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.key(1), (8, 4, cfg.d_model))
-    ref, _ = moelib.apply_moe(p, cfg, x)
+    ref, _, ref_counts = moelib.apply_moe(p, cfg, x)
     worst = 0.0
     for shape in [(2, 4), (4, 2), (8, 1), (1, 8)]:
         mesh = make_production_mesh(shape=shape, axes=("data", "model"))
         ctx = shlib.ShardingContext(mesh)
         with mesh:
             with shlib.use(ctx):
-                out, _ = jax.jit(
+                out, _, counts = jax.jit(
                     lambda p, x: moelib.apply_moe(p, cfg, x))(p, x)
         worst = max(worst, float(jnp.max(jnp.abs(out - ref))))
+        # the routing counters come from the shards' own routing
+        assert (counts == ref_counts).all(), (shape, counts, ref_counts)
     print("WORST", worst)
     assert worst < 1e-4, worst
 """)

@@ -10,7 +10,8 @@ that runs this file loads the TPU library.
 
 Shapes are the ones ``chip_smoke.py`` serves: 8 lanes, block-table rows
 of 15 pages (160-token requests, 64-token prefill chunks), a pool of
-4,096 16-token pages.
+4,096 16-token pages; and the Mellum2 cell's: 16 lanes, rows of 529 pages
+(8,192-token requests, 256-token chunks), 1,024-token windows.
 """
 import functools
 import os
@@ -83,9 +84,18 @@ _KERNEL_SHAPES = [
 ]
 
 
-@pytest.mark.parametrize("arch,dtype,lanes,row,pages,chips", _KERNEL_SHAPES)
+# Mellum2's grouping (4 KV heads, 8 query heads each) with its window
+_WINDOW_SHAPES = [
+    pytest.param("mellum2-12b-a2.5b-d12", "bfloat16", 16, 529, 4096, 1,
+                 1024, id="mellum2-window"),
+]
+
+
+@pytest.mark.parametrize("arch,dtype,lanes,row,pages,chips,window",
+                         [pytest.param(*p.values, None, id=p.id)
+                          for p in _KERNEL_SHAPES] + _WINDOW_SHAPES)
 def test_paged_flash_decode_compiles(one_chip, arch, dtype, lanes, row,
-                                     pages, chips):
+                                     pages, chips, window):
     cfg = get_config(arch)
     H, KV = cfg.num_heads // chips, cfg.num_kv_heads // chips
     hd = cfg.head_dim
@@ -95,7 +105,8 @@ def test_paged_flash_decode_compiles(one_chip, arch, dtype, lanes, row,
          jax.ShapeDtypeStruct((pages, KV, PAGE, hd), dtype),
          jax.ShapeDtypeStruct((lanes, row), jnp.int32),
          jax.ShapeDtypeStruct((lanes,), jnp.int32)), one_chip)
-    kernel = functools.partial(paged_flash_decode, interpret=False)
+    kernel = functools.partial(paged_flash_decode, interpret=False,
+                               window=window)
     compiled = jax.jit(kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -113,6 +124,27 @@ def test_qwen2_decode_step_compiles_with_kernel(one_chip, monkeypatch):
                           states).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().argument_size_in_bytes < HBM_BYTES
+
+
+def test_mellum2_decode_step_compiles_and_fits_one_chip(one_chip,
+                                                         monkeypatch):
+    """The Mellum2 cell's decode step (12 layers, every expert, 16
+    lanes, 4,096 pages, 529-page rows): the windowed and the full kernel
+    are both in it, and weights, pools and temporaries fit 16 GB."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = get_config("mellum2-12b-a2.5b-d12")
+    params = _placed(abstract_params(cfg), one_chip)
+    states = _placed(init_paged_states(cfg, PAGES, PAGE,
+                                       make=jax.ShapeDtypeStruct), one_chip)
+    i32 = jnp.int32
+    batch = _placed({"tokens": jax.ShapeDtypeStruct((16, 1), i32),
+                     "block_tables": jax.ShapeDtypeStruct((16, 529), i32),
+                     "lengths": jax.ShapeDtypeStruct((16,), i32)}, one_chip)
+    step = jax.jit(make_paged_decode_step(cfg), donate_argnums=2)
+    compiled = step.lower(params, batch, states).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
 def test_starcoder2_7b_decode_step_compiles_on_four_chips(topo,
