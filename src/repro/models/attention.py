@@ -263,6 +263,13 @@ def prefill(p, cfg, blk, x, positions, max_len: Optional[int] = None
 # land there harmlessly.  The block table (pages_per_seq ids per sequence)
 # is SHARED across layers: every layer writes the same logical positions,
 # so one allocation describes all of them.
+#
+# The paged entry points take a STACK of layer pools (L, P, KV, ps, hd) and
+# the index of their layer in it: a scanned model carries its stacked pools
+# through the layer scan, so each layer writes its tokens' KV into the stack
+# in place and reads its pages from it, and no layer's pool is ever sliced
+# out or written back.  An unscanned layer passes its pool as a stack of one
+# at index 0.
 
 
 def init_paged_kv_cache(cfg, blk, num_pages: int, page_size: int,
@@ -288,27 +295,39 @@ def _page_of(block_table, pos, page_size):
     return jnp.take_along_axis(block_table, idx, axis=-1)
 
 
-def _scatter_pages(pages, vals, block_table, start):
+def _rows(page, slot, kv: int):
+    """Index arrays naming, for each of n positions (page[i], slot[i]),
+    the row of hd values that holds each of its ``kv`` heads: (n, KV)
+    after broadcasting.  The pools are written and read a row at a time:
+    a window across the heads axis makes XLA lay the whole pool out
+    afresh with the heads innermost, a copy of every pool at each step."""
+    return page[:, None], jnp.arange(kv)[None, :], slot[:, None]
+
+
+def _scatter_pages(pages, layer, vals, block_table, start):
     """Write ``vals`` (n, KV, hd) at positions start..start+n-1 of one
-    sequence.  pages (P, KV, ps, hd); block_table (pages_per_seq,)."""
-    ps = pages.shape[2]
+    sequence into layer ``layer`` of the stacked pool (L, P, KV, ps, hd);
+    block_table (pages_per_seq,)."""
+    ps = pages.shape[3]
     pos = start + jnp.arange(vals.shape[0])
-    page = _page_of(block_table, pos, ps)
-    return pages.at[page, :, pos % ps].set(vals.astype(pages.dtype))
+    rows = _rows(_page_of(block_table, pos, ps), pos % ps, pages.shape[2])
+    return pages.at[(layer,) + rows].set(vals.astype(pages.dtype))
 
 
-def _gather_pages(pages, block_table, max_ctx: int):
-    """Dense (max_ctx, KV, hd) view of one sequence's pages (garbage past
-    the written length — callers mask by position)."""
-    ps = pages.shape[2]
+def _gather_pages(pages, layer, block_table, max_ctx: int):
+    """Dense (max_ctx, KV, hd) view of one sequence's pages in layer
+    ``layer`` of the stacked pool (garbage past the written length --
+    callers mask by position)."""
+    ps = pages.shape[3]
     pos = jnp.arange(max_ctx)
-    page = _page_of(block_table, pos, ps)
-    return pages[page, :, pos % ps]
+    rows = _rows(_page_of(block_table, pos, ps), pos % ps, pages.shape[2])
+    return pages[(layer,) + rows]
 
 
-def paged_prefill_chunk(p, cfg, blk, x, cache, block_table, start
+def paged_prefill_chunk(p, cfg, blk, x, cache, block_table, start, layer
                         ) -> Tuple[jnp.ndarray, dict]:
-    """One prompt chunk through paged attention.  x (1, C, d) holds tokens
+    """One prompt chunk through paged attention at layer ``layer`` of the
+    stacked pools in ``cache``.  x (1, C, d) holds tokens
     at absolute positions start..start+C-1 (tail may be padding — pad
     positions are only ever read causally by pad queries, and decode
     overwrites their page slots before reading them).
@@ -322,16 +341,29 @@ def paged_prefill_chunk(p, cfg, blk, x, cache, block_table, start
     B, C, _ = x.shape
     positions = start + jnp.arange(C)[None]                    # (1, C)
     q, k, v = _project_qkv(p, cfg, x, positions, blk)
-    k_pages = _scatter_pages(cache["k_pages"], k[0], block_table, start)
-    v_pages = _scatter_pages(cache["v_pages"], v[0], block_table, start)
-    max_ctx = block_table.shape[-1] * k_pages.shape[2]
-    kd = _gather_pages(k_pages, block_table, max_ctx)[None]    # (1,ctx,KV,hd)
-    vd = _gather_pages(v_pages, block_table, max_ctx)[None]
+    k_pages = _scatter_pages(cache["k_pages"], layer, k[0], block_table,
+                             start)
+    v_pages = _scatter_pages(cache["v_pages"], layer, v[0], block_table,
+                             start)
+    max_ctx = block_table.shape[-1] * k_pages.shape[3]
+    kd = _gather_pages(k_pages, layer, block_table, max_ctx)[None]
+    vd = _gather_pages(v_pages, layer, block_table, max_ctx)[None]
     out = chunked_causal_attention(q, kd, vd, window=blk.window,
                                    q_offset=start, q_chunk=cfg.attn_chunk,
                                    kv_chunk=cfg.attn_chunk)
     out = out.reshape(B, C, cfg.num_heads * cfg.head_dim) @ p["wo"]
     return out, {"k_pages": k_pages, "v_pages": v_pages}
+
+
+def layer_view(k_pages, v_pages, block_tables, layer):
+    """What the paged kernel reads for layer ``layer`` of stacked pools
+    (L, P, KV, ps, hd): both pools as one pool of L * P pages (a reshape
+    of the leading axes, which moves no data), and the block tables
+    offset to that layer's pages in it."""
+    L, P = k_pages.shape[:2]
+    return (k_pages.reshape(L * P, *k_pages.shape[2:]),
+            v_pages.reshape(L * P, *v_pages.shape[2:]),
+            block_tables + layer * P)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -359,31 +391,32 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
         check_vma=False)(q, k_pages, v_pages, block_tables, lengths)
 
 
-def paged_decode(p, cfg, blk, x, cache, block_tables, lengths
+def paged_decode(p, cfg, blk, x, cache, block_tables, lengths, layer
                  ) -> Tuple[jnp.ndarray, dict]:
-    """One-token decode over the shared page pool.
+    """One-token decode over the shared page pool, at layer ``layer`` of
+    the stacked pools in ``cache``.
 
     x (B, 1, d); lengths (B,) tokens already written per lane.  Writes the
     new token's KV at position lengths[b] of each lane's block table, then
     attends lengths[b]+1 tokens (the last ``blk.window`` of them on a
     windowed layer) via the paged flash-decode kernel (TPU) or its XLA
-    gather twin (CPU).  Inactive lanes pass a null block table
-    (all page 0) and length 0 — their writes and reads hit the reserved
-    null page and their outputs are discarded by the engine.
+    gather twin (CPU), through :func:`layer_view`.  Inactive lanes pass
+    a null block table (all page 0) and length 0 — their writes and reads
+    hit the reserved null page and their outputs are discarded by the
+    engine.
     """
     B = x.shape[0]
     hd = cfg.head_dim
     q, k, v = _project_qkv(p, cfg, x, lengths[:, None], blk)
-    ps = cache["k_pages"].shape[2]
+    KV, ps = cache["k_pages"].shape[2:4]
     page = _page_of(block_tables, lengths[:, None], ps)[:, 0]  # (B,)
-    slot = lengths % ps
+    rows = (layer,) + _rows(page, lengths % ps, KV)
     dtype = cache["k_pages"].dtype
-    k_pages = cache["k_pages"].at[page, :, slot].set(
-        k[:, 0].astype(dtype))
-    v_pages = cache["v_pages"].at[page, :, slot].set(
-        v[:, 0].astype(dtype))
+    k_pages = cache["k_pages"].at[rows].set(k[:, 0].astype(dtype))
+    v_pages = cache["v_pages"].at[rows].set(v[:, 0].astype(dtype))
     windowed = {} if blk.window is None else {"window": blk.window}
-    out = paged_attention(q[:, 0], k_pages, v_pages, block_tables,
+    out = paged_attention(q[:, 0],
+                          *layer_view(k_pages, v_pages, block_tables, layer),
                           lengths + 1, **windowed)
     out = out.reshape(B, 1, cfg.num_heads * hd).astype(x.dtype)
     out = out @ p["wo"]

@@ -246,11 +246,11 @@ def _apply_block(cfg, blk, p, x, positions, state, mode, max_len=None,
         elif mode == "paged_prefill":
             mix, new_state = attention.paged_prefill_chunk(
                 p["mixer"], cfg, blk, h, state, paged["block_table"],
-                paged["start"])
+                paged["start"], paged["layer"])
         elif mode == "paged_decode":
             mix, new_state = attention.paged_decode(
                 p["mixer"], cfg, blk, h, state, paged["block_tables"],
-                paged["lengths"])
+                paged["lengths"], paged["layer"])
         else:
             mix, new_state = attention.decode(p["mixer"], cfg, blk, h, state)
     elif blk.mixer == "mlstm":
@@ -337,30 +337,67 @@ def _stack_counts(counts):
     return jnp.concatenate(counts) if counts else None
 
 
+def _apply_unscanned(cfg, blk, p, x, positions, state, mode, max_len=None,
+                     paged=None):
+    """``_apply_block`` on a layer outside the scan.  In the paged modes its
+    pools go in as a stack of one layer, at index 0."""
+    if paged is None:
+        return _apply_block(cfg, blk, p, x, positions, state, mode,
+                            max_len=max_len)
+    stack = jax.tree_util.tree_map(lambda a: a[None], state)
+    x, ns, a, c = _apply_block(cfg, blk, p, x, positions, stack, mode,
+                               paged=dict(paged, layer=0))
+    return x, jax.tree_util.tree_map(lambda a: a[0], ns), a, c
+
+
 def _scan_layers(cfg, layers, x, positions, states, mode, max_len=None,
                  paged=None):
     """Grouped scan over layers.  Returns (x, new_states, aux_sum,
-    expert counts (L_moe, E) or None)."""
+    expert counts (L_moe, E) or None).
+
+    The paged modes carry the stacked page pools through the scan, and
+    group g's layers write and read layer g of them in place: the pools
+    are never sliced per layer nor rebuilt as the scan's output, so the
+    donated input is the output.  The other modes scan per-group states
+    in and new ones out."""
     pattern = cfg.layer_pattern()
     P = len(cfg.pattern)
     n_groups, rem = _layer_layout(cfg)
     aux_total = jnp.zeros((), jnp.float32)
     counts = []
 
-    if n_groups > 0:
+    def group(xc, aux, gp, gs, at):
+        """Positions 0..P-1 of one group; gs per position (or None)."""
+        new_gs, cs = [], []
+        for j in range(P):
+            xc, ns, a, c = _apply_block(
+                cfg, pattern[j], gp[j], xc, positions,
+                gs[j] if gs is not None else None, mode,
+                max_len=max_len, paged=at)
+            new_gs.append(ns)
+            aux = aux + a
+            if c is not None:
+                cs.append(c)
+        return xc, aux, new_gs, cs
+
+    new_scan_states = states["scan"] if states is not None else []
+    cs = []
+    if n_groups > 0 and paged is not None:
+        def pools_body(carry, xs):
+            xc, aux, pools = carry
+            gp, g = xs
+            xc, aux, pools, cs = group(xc, aux, gp, pools,
+                                       dict(paged, layer=g))
+            return (xc, aux, pools), cs
+
+        (x, aux_total, new_scan_states), cs = jax.lax.scan(
+            pools_body, (x, aux_total, states["scan"]),
+            (layers["scan"], jnp.arange(n_groups)))
+    elif n_groups > 0:
         def group_body(carry, xs):
             xc, aux = carry
             gp, gs = xs            # per-position lists stacked over groups
-            new_gs, cs = [], []
-            for j in range(P):
-                xc, ns, a, c = _apply_block(
-                    cfg, pattern[j], gp[j], xc, positions,
-                    gs[j] if gs is not None else None, mode,
-                    max_len=max_len, paged=paged)
-                new_gs.append(ns)
-                aux = aux + a
-                if c is not None:
-                    cs.append(c)
+            xc, aux, new_gs, cs = group(xc, aux, gp, gs, None)
             return (xc, aux), (new_gs, cs)
 
         body = group_body
@@ -370,18 +407,16 @@ def _scan_layers(cfg, layers, x, positions, states, mode, max_len=None,
         scan_states = states["scan"] if states is not None else None
         (x, aux_total), (new_scan_states, cs) = jax.lax.scan(
             body, (x, aux_total), (layers["scan"], scan_states))
-        if cs:                 # (groups, positions, E): layer g * P + j
-            counts.append(jnp.stack(cs, axis=1).reshape(-1, cs[0].shape[-1]))
-    else:
-        new_scan_states = states["scan"] if states is not None else []
+    if cs:                     # (groups, positions, E): layer g * P + j
+        counts.append(jnp.stack(cs, axis=1).reshape(-1, cs[0].shape[-1]))
 
     new_rem = []
     for j in range(rem):
         blk = pattern[n_groups * P + j]
         st = states["rem"][j] if states is not None else None
         def blk_fn(p_, x_, st_, blk=blk):
-            return _apply_block(cfg, blk, p_, x_, positions, st_, mode,
-                                max_len=max_len, paged=paged)
+            return _apply_unscanned(cfg, blk, p_, x_, positions, st_, mode,
+                                    max_len=max_len, paged=paged)
         if cfg.remat and mode == "train":
             blk_fn = jax.checkpoint(
                 blk_fn, policy=jax.checkpoint_policies.nothing_saveable)
@@ -403,9 +438,9 @@ def _flat_layers(cfg, layers, x, positions, states, mode, max_len=None,
     new_states, counts = [], []
     for i, blk in enumerate(pattern):
         st = states["flat"][i] if states is not None else None
-        x, ns, a, c = _apply_block(cfg, blk, layers["flat"][i], x,
-                                   positions, st, mode, max_len=max_len,
-                                   paged=paged)
+        x, ns, a, c = _apply_unscanned(cfg, blk, layers["flat"][i], x,
+                                       positions, st, mode, max_len=max_len,
+                                       paged=paged)
         new_states.append(ns)
         aux_total = aux_total + a
         if c is not None:

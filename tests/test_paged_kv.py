@@ -2,6 +2,7 @@
 chunked-prefill interleaving, dense/paged engine parity, and the
 prefix-sharing cache (refcounts, COW forks, LRU eviction)."""
 import dataclasses
+import functools
 import os
 
 import jax
@@ -13,6 +14,7 @@ from repro.cluster.request import Request
 from repro.configs import get_config, reduced
 from repro.kernels import ref
 from repro.kernels.decode_attention import paged_flash_decode
+from repro.models import attention
 from repro.models.transformer import init_params
 from repro.serving.engine import ServeEngine
 from repro.serving.paged_kv import (BlockTable, PagePool, PrefixCache,
@@ -43,10 +45,13 @@ def _kernel_paged_decode(q, kp, vp, tbl, lens, pages_per_block=None,
 
 
 def _paged_case(B, KV, H, hd, ps, npages, lengths, seed=0,
-                dtype=jnp.float32):
+                dtype=jnp.float32, stack=None):
     """Random pool + per-sequence scrambled page tables, plus the dense
     (B, KV, S, hd) float32 cache holding the same tokens in order.  q and
-    the pools come in ``dtype``; the dense view holds their values."""
+    the pools come in ``dtype``; the dense view holds their values.  With
+    ``stack`` (L, l) the pools are a stack of L layer pools (L, P, KV, ps,
+    hd), layer l as above and every other layer NaN: a read of any other
+    layer's pages shows."""
     rng = np.random.default_rng(seed)
     pool_pages = 1 + B * npages                    # page 0 = null
     perm = 1 + rng.permutation(B * npages)         # scrambled, non-contig
@@ -66,40 +71,71 @@ def _paged_case(B, KV, H, hd, ps, npages, lengths, seed=0,
                    for b in range(B)])
     vd = np.stack([vp[tables[b]].transpose(1, 0, 2, 3).reshape(KV, S, hd)
                    for b in range(B)])
+    if stack is not None:
+        L, layer = stack
+        nan = jnp.full((L,) + k_pages.shape, jnp.nan, k_pages.dtype)
+        k_pages = nan.at[layer].set(k_pages)
+        v_pages = nan.at[layer].set(v_pages)
     return (q, k_pages, v_pages, jnp.asarray(tables),
             jnp.asarray(lengths, jnp.int32), jnp.asarray(kd),
             jnp.asarray(vd))
 
 
-# (B, KV, H, hd, ps, npages, lengths, pages_per_block, dtype): lengths end
-# mid-page, fill the row exactly, span several blocks, and sit beside
-# length-1 lanes (the engine's idle lanes)
+def _kernel_view(kp, vp, tbl, stack):
+    """The kernel's pools and tables: as made, or what ``paged_decode``
+    hands it for layer l of a stack (``attention.layer_view``)."""
+    if stack is None:
+        return kp, vp, tbl
+    return attention.layer_view(kp, vp, tbl, stack[1])
+
+
+def _layer(pages, stack):
+    return pages if stack is None else pages[stack[1]]
+
+
+# (B, KV, H, hd, ps, npages, lengths, pages_per_block, dtype, stack):
+# lengths end mid-page, fill the row exactly, span several blocks, and sit
+# beside length-1 lanes (the engine's idle lanes); ``stack`` (L, l) reads
+# layer l of a stack of L layer pools, as the layer scan does
 _KERNEL_CASES = {
     # qwen2's grouping (KV 2, G 6); the derived block holds the whole row
-    "qwen2-heads": (4, 2, 12, 64, 8, 6, [1, 7, 23, 48], None, "float32"),
+    "qwen2-heads": (4, 2, 12, 64, 8, 6, [1, 7, 23, 48], None, "float32",
+                    None),
     # the same lanes in 4-page blocks: 6 pages is not a multiple of 4
     "qwen2-heads-small-blocks": (4, 2, 12, 64, 8, 6, [1, 7, 23, 48], 4,
-                                 "float32"),
+                                 "float32", None),
     # starcoder2-7b's grouping on one chip of four (KV 1, G 9)
     "starcoder2-chip-heads": (5, 1, 9, 32, 8, 7, [1, 56, 13, 1, 30], 2,
-                              "float32"),
+                              "float32", None),
     # one page per block: the prefetch crosses a block at every page
-    "one-page-blocks": (3, 2, 4, 32, 8, 5, [40, 1, 17], 1, "float32"),
+    "one-page-blocks": (3, 2, 4, 32, 8, 5, [40, 1, 17], 1, "float32", None),
     # bf16 pools and query against the float32 oracle
-    "bf16-pools": (4, 2, 12, 64, 16, 5, [1, 80, 33, 1], 2, "bfloat16"),
+    "bf16-pools": (4, 2, 12, 64, 16, 5, [1, 80, 33, 1], 2, "bfloat16",
+                   None),
     # empty lanes first, between and last: the prefetch skips them
-    "empty-lanes": (6, 2, 4, 32, 8, 5, [0, 30, 0, 0, 9, 0], 2, "float32"),
+    "empty-lanes": (6, 2, 4, 32, 8, 5, [0, 30, 0, 0, 9, 0], 2, "float32",
+                    None),
+    # the last layer of a stack of three, in blocks, and the middle one
+    # of a bf16 stack
+    "stack-last-layer": (4, 2, 12, 64, 8, 6, [1, 7, 23, 48], 4, "float32",
+                         (3, 2)),
+    "stack-bf16-middle-layer": (4, 2, 12, 64, 16, 5, [1, 80, 33, 1], 2,
+                                "bfloat16", (3, 1)),
 }
 
 
 @pytest.mark.parametrize("case", list(_KERNEL_CASES))
 def test_paged_kernel_matches_dense_ref_ragged_scrambled(case):
     """Kernel (mode per REPRO_PAGED_TEST_MODE) vs the dense decode
-    oracle through deliberately non-contiguous page tables."""
-    B, KV, H, hd, ps, npages, lengths, ppb, dtype = _KERNEL_CASES[case]
+    oracle through deliberately non-contiguous page tables, on one pool
+    or at one layer of a stack of them."""
+    B, KV, H, hd, ps, npages, lengths, ppb, dtype, stack = \
+        _KERNEL_CASES[case]
     q, kp, vp, tbl, lens, kd, vd = _paged_case(B, KV, H, hd, ps, npages,
-                                               lengths, dtype=dtype)
-    got = _kernel_paged_decode(q, kp, vp, tbl, lens, pages_per_block=ppb)
+                                               lengths, dtype=dtype,
+                                               stack=stack)
+    got = _kernel_paged_decode(q, *_kernel_view(kp, vp, tbl, stack), lens,
+                               pages_per_block=ppb)
     assert got.shape == q.shape and got.dtype == q.dtype
     got = np.asarray(got.astype(jnp.float32))
     want = np.asarray(ref.decode_ref(q.astype(jnp.float32), kd, vd, lens))
@@ -112,18 +148,21 @@ def test_paged_kernel_matches_dense_ref_ragged_scrambled(case):
     np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
     # and the XLA serving-path oracle agrees with both
     ora = ref.paged_decode_ref(q.astype(jnp.float32),
-                               kp.astype(jnp.float32),
-                               vp.astype(jnp.float32), tbl, lens)
+                               _layer(kp, stack).astype(jnp.float32),
+                               _layer(vp, stack).astype(jnp.float32), tbl,
+                               lens)
     np.testing.assert_allclose(np.asarray(ora), want, atol=1e-3, rtol=1e-3)
 
 
-# (window, pages_per_block, lengths): Mellum2's grouping (KV 4, G 8) on
-# 8-token pages; lanes shorter than, equal to and past the window, a
-# window that starts mid-page and one that starts on a page boundary
+# (window, pages_per_block, lengths, stack): Mellum2's grouping (KV 4, G
+# 8) on 8-token pages; lanes shorter than, equal to and past the window, a
+# window that starts mid-page and one that starts on a page boundary, and
+# the window at layer l of a stack of L layer pools (L, l)
 _WINDOW_CASES = {
-    "mid-page": (20, 2, [0, 1, 19, 20, 21, 57, 96]),
-    "page-aligned": (16, 2, [16, 17, 40, 96, 0, 3, 64]),
-    "one-page-blocks": (20, 1, [57, 1, 96, 33, 0, 20, 8]),
+    "mid-page": (20, 2, [0, 1, 19, 20, 21, 57, 96], None),
+    "page-aligned": (16, 2, [16, 17, 40, 96, 0, 3, 64], None),
+    "one-page-blocks": (20, 1, [57, 1, 96, 33, 0, 20, 8], None),
+    "stack-mid-page": (20, 2, [0, 1, 19, 20, 21, 57, 96], (3, 2)),
 }
 
 
@@ -133,32 +172,77 @@ def test_windowed_paged_kernel_matches_ref_with_start_offset(case):
     against ``paged_decode_ref(window=)`` and the dense oracle.  Pages
     wholly before the window hold NaN for the kernel: it must never copy
     them in (the XLA oracle gathers every page, so it gets them clean)."""
-    window, ppb, lengths = _WINDOW_CASES[case]
+    window, ppb, lengths, stack = _WINDOW_CASES[case]
     B, KV, H, hd, ps, npages = len(lengths), 4, 32, 32, 8, 12
     q, kp, vp, tbl, lens, kd, vd = _paged_case(B, KV, H, hd, ps, npages,
-                                               lengths, seed=4)
+                                               lengths, seed=4, stack=stack)
     want = np.asarray(ref.decode_ref(q, kd, vd, lens, window))
     tables = np.asarray(tbl)
+    at = () if stack is None else (stack[1],)
     for b, ln in enumerate(lengths):
         before = tables[b, :max(ln - window, 0) // ps]
         if _MODE == "interpret":
-            kp = kp.at[before].set(jnp.nan)
-            vp = vp.at[before].set(jnp.nan)
-    got = np.asarray(_kernel_paged_decode(q, kp, vp, tbl, lens,
-                                          pages_per_block=ppb,
-                                          window=window))
+            kp = kp.at[at + (before,)].set(jnp.nan)
+            vp = vp.at[at + (before,)].set(jnp.nan)
+    got = np.asarray(_kernel_paged_decode(
+        q, *_kernel_view(kp, vp, tbl, stack), lens, pages_per_block=ppb,
+        window=window))
     live = np.asarray(lengths) > 0
     np.testing.assert_allclose(got[live], want[live], atol=1e-3, rtol=1e-3)
     if _MODE == "interpret":
         assert not got[~live].any()
-    clean = ref.paged_decode_ref(q, *_paged_case(B, KV, H, hd, ps, npages,
-                                                 lengths, seed=4)[1:5],
-                                 window=window)
+    _, kc, vc, tc, lc = _paged_case(B, KV, H, hd, ps, npages, lengths,
+                                    seed=4, stack=stack)[:5]
+    clean = ref.paged_decode_ref(q, _layer(kc, stack), _layer(vc, stack),
+                                 tc, lc, window=window)
     np.testing.assert_allclose(np.asarray(clean), want, atol=1e-5,
                                rtol=1e-5)
     full = np.asarray(ref.decode_ref(q, kd, vd, lens))
     far = np.asarray(lengths) > window
     assert not np.allclose(full[far], want[far], atol=1e-3)
+
+
+@pytest.mark.parametrize("window", [None, 20])
+@pytest.mark.parametrize("stack", [(1, 0), (3, 1)])
+def test_paged_decode_writes_its_rows_of_its_layer_in_place(monkeypatch,
+                                                           stack, window):
+    """One decode step at layer l of a stack of L layer pools, through
+    the interpreted kernel: each lane's new K/V rows land at its page and
+    slot of layer l, every other row of every layer stays bit-identical,
+    and the output is the oracle's on layer l after the write."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "paged_flash_decode", functools.partial(
+        paged_flash_decode, interpret=True, pages_per_block=2))
+    cfg = reduced(get_config("qwen2-1.5b"))
+    blk = dataclasses.replace(cfg.layer_pattern()[0], window=window)
+    L, layer = stack
+    B, ps, npages, lengths = 4, 8, 6, [0, 7, 23, 40]
+    KV, hd = cfg.num_kv_heads, cfg.head_dim
+    _, kp, vp, tbl, lens, _, _ = _paged_case(B, KV, 4, hd, ps, npages,
+                                             lengths, seed=5)
+    ks = jax.random.split(jax.random.key(6), 4)
+    k_all = jax.random.normal(ks[0], (L,) + kp.shape).at[layer].set(kp)
+    v_all = jax.random.normal(ks[1], (L,) + vp.shape).at[layer].set(vp)
+    p = attention.init_attention(ks[2], cfg, jnp.float32)
+    x = jax.random.normal(ks[3], (B, 1, cfg.d_model))
+    out, new = attention.paged_decode(p, cfg, blk, x,
+                                      {"k_pages": k_all, "v_pages": v_all},
+                                      tbl, lens, layer)
+    _, k, v = attention._project_qkv(p, cfg, x, lens[:, None], blk)
+    page = np.asarray(tbl)[np.arange(B), np.asarray(lengths) // ps]
+    slot = np.asarray(lengths) % ps
+    for old, got, row in ((k_all, new["k_pages"], k), (v_all, new["v_pages"],
+                                                      v)):
+        want = np.array(old)
+        want[layer, page, :, slot] = np.asarray(row[:, 0])
+        np.testing.assert_array_equal(np.asarray(got), want)
+    q = attention._project_qkv(p, cfg, x, lens[:, None], blk)[0][:, 0]
+    attn = ref.paged_decode_ref(q, new["k_pages"][layer],
+                                new["v_pages"][layer], tbl, lens + 1,
+                                window)
+    want = attn.reshape(B, 1, -1) @ p["wo"]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
 
 
 @pytest.mark.parametrize("fill", ["null-page", "out-of-range"])

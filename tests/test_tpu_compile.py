@@ -111,9 +111,16 @@ def test_paged_flash_decode_compiles(one_chip, arch, dtype, lanes, row,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _layer_pools(cfg, chips=1):
+    """Bytes of one layer's bf16 K and V pools on each of ``chips``."""
+    return 2 * PAGES * cfg.num_kv_heads // chips * PAGE * cfg.head_dim * 2
+
+
 def test_qwen2_decode_step_compiles_with_kernel(one_chip, monkeypatch):
     """The published-width qwen2-1.5b paged decode step, as the engine
-    jits it, holds the Pallas kernel and fits one chip."""
+    jits it, holds the Pallas kernel and fits one chip.  Its temporaries
+    hold no copy of a layer's pool: the pools are written in place, in
+    the layout they are kept in."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
     cfg = get_config("qwen2-1.5b")
     params = _placed(abstract_params(cfg), one_chip)
@@ -123,14 +130,17 @@ def test_qwen2_decode_step_compiles_with_kernel(one_chip, monkeypatch):
     compiled = step.lower(params, _decode_inputs(cfg, one_chip),
                           states).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().argument_size_in_bytes < HBM_BYTES
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < HBM_BYTES
+    assert mem.temp_size_in_bytes < _layer_pools(cfg)
 
 
 def test_mellum2_decode_step_compiles_and_fits_one_chip(one_chip,
                                                          monkeypatch):
     """The Mellum2 cell's decode step (12 layers, every expert, 16
     lanes, 4,096 pages, 529-page rows): the windowed and the full kernel
-    are both in it, and weights, pools and temporaries fit 16 GB."""
+    are both in it, weights, pools and temporaries fit 16 GB, and the
+    temporaries hold no copy of a layer's pool."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
     cfg = get_config("mellum2-12b-a2.5b-d12")
     params = _placed(abstract_params(cfg), one_chip)
@@ -145,13 +155,15 @@ def test_mellum2_decode_step_compiles_and_fits_one_chip(one_chip,
     assert compiled.as_text().count("tpu_custom_call") >= 2
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert mem.temp_size_in_bytes < _layer_pools(cfg)
 
 
 def test_starcoder2_7b_decode_step_compiles_on_four_chips(topo,
                                                           monkeypatch):
     """starcoder2-7b over a (1, 4) mesh: the kernel runs inside
-    shard_map with its KV heads split over 'model', and each chip holds
-    a quarter of the weights."""
+    shard_map with its KV heads split over 'model', each chip holds a
+    quarter of the weights, and no chip's temporaries hold a copy of its
+    share of a layer's pool."""
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
     cfg = get_config("starcoder2-7b")
     mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
@@ -166,5 +178,7 @@ def test_starcoder2_7b_decode_step_compiles_on_four_chips(topo,
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     weight_bytes = 2 * cfg.param_count()
-    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    mem = compiled.memory_analysis()
+    per_chip = mem.argument_size_in_bytes
     assert per_chip < 0.3 * weight_bytes + 2e9, per_chip
+    assert mem.temp_size_in_bytes < _layer_pools(cfg, chips=4)
