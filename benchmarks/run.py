@@ -33,7 +33,7 @@ Mapping to the paper:
   stress   -> (systems) saturation ramp: Poisson arrival rate climbs a
               geometric ladder per scheduler until deadline goodput
               collapses (per-stage throughput/goodput + p50/p95/p99,
-              saturation knee, overlap-vs-serial stepping A/B);
+              saturation knee);
               writes BENCH_stress.json with --out-dir
 """
 from __future__ import annotations

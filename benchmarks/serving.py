@@ -163,16 +163,11 @@ def bench_closed_loop(scale: str = "quick", n_edge: int = 4,
 
     def scheds():
         return {
-            "lad-ts": PolicyScheduler("lad-ts", acfg, states,
-                                      num_engines=n_edge,
+            "lad-ts": PolicyScheduler("lad-ts", acfg, states, p.layout,
                                       n_max=p.max_tasks),
-            "deadline": make_scheduler("deadline", n_edge),
-            "prefix-affinity": make_scheduler("prefix-affinity", n_edge,
-                                              qos=True),
-            "jsq": make_scheduler("jsq", n_edge),
-            "round-robin": make_scheduler("round-robin", n_edge),
-            "random": make_scheduler("random", n_edge),
-            "local": make_scheduler("local", n_edge),
+            **{name: make_scheduler(name, n_edge, qos=True)
+               for name in ("deadline", "prefix-affinity", "jsq",
+                            "round-robin", "random", "local")},
         }
 
     def qos_suffix(stats):
@@ -182,7 +177,7 @@ def bench_closed_loop(scale: str = "quick", n_edge: int = 4,
     rows, records = [], []
     # --- same Scheduler interface against the core.env simulator ----------
     for name, s in scheds().items():
-        if getattr(s, "prefix_obs", False):
+        if s.layout.prefix:
             continue   # the slot-based sim has no KV model to be warm in
         t0 = time.monotonic()
         r = evaluate_scheduler(s, p, episodes=2, key=jax.random.key(1))
@@ -214,7 +209,7 @@ def bench_closed_loop(scale: str = "quick", n_edge: int = 4,
         for e in engines:
             e.reset()             # also clears each prefix cache: every
         # scheduler starts COLD, so hit rates compare placement quality
-        cluster = EdgeCluster(engines, s, seed=seed, qos_obs=True)
+        cluster = EdgeCluster(engines, s, seed=seed)
         trace = poisson_trace(num_requests, rate=rate,
                               prompt_len=prompt_len,
                               max_new_tokens=gen_tokens,

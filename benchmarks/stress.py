@@ -12,8 +12,7 @@ Protocol
 --------
 For each scheduler, the SAME geometric ladder of offered arrival rates is
 replayed stage by stage (identical per-stage traces across schedulers —
-same seeds), each stage on a freshly reset fleet driven with overlapped
-dispatch/collect stepping.  Each stage offers load for a FIXED window
+same seeds), each stage on a freshly reset fleet.  Each stage offers load for a FIXED window
 (``window_s``), so the number of arrivals scales with the stage's rate:
 past fleet capacity the backlog — and with it queueing delay and
 deadline misses — grows with offered load, which is what makes goodput
@@ -34,11 +33,6 @@ only converts into deadline misses and watchdog shedding, so goodput is
 expected to be monotone non-increasing from there on — the invariant the
 CI smoke asserts on ``BENCH_stress.json``.  ``saturation_rate`` reports
 the offered rate at that knee.
-
-An overlap A/B pair rides along: the heaviest stage replayed through
-the identical fleet with ``overlap=True`` vs ``overlap=False`` cluster
-stepping, recording the closed-loop wall-time speedup of dispatching all
-engines before collecting any (``bench == "stress_ab"``).
 
 Run it:  PYTHONPATH=src python -m benchmarks.run --only stress --out-dir .
 """
@@ -75,16 +69,14 @@ def stress_qos_mix(gen_tokens: int, prompt_len: int,
 
 def run_stage(engines, scheduler_name: str, *, rate: float,
               num_requests: int, prompt_len: int, gen_tokens: int,
-              vocab: int, mix, seed: int, overlap: bool = True) -> dict:
+              vocab: int, mix, seed: int) -> dict:
     """One (scheduler, offered-rate) stage on a freshly reset fleet."""
     E = len(engines)
     for e in engines:
         e.reset()
-    sched = (make_scheduler(scheduler_name, E, qos=True)
-             if scheduler_name in ("failure-aware", "prefix-affinity")
-             else make_scheduler(scheduler_name, E))
-    cluster = EdgeCluster(engines, sched, seed=seed, qos_obs=True,
-                          overlap=overlap, retry=RetryPolicy())
+    cluster = EdgeCluster(engines, make_scheduler(scheduler_name, E,
+                                                  qos=True),
+                          seed=seed, retry=RetryPolicy())
     trace = poisson_trace(num_requests, rate=rate, prompt_len=prompt_len,
                           max_new_tokens=gen_tokens, vocab_size=vocab,
                           num_origins=E, seed=seed, qos_mix=mix)
@@ -96,7 +88,6 @@ def run_stage(engines, scheduler_name: str, *, rate: float,
     return {
         "offered_rate": float(rate),
         "wall_s": wall,
-        "overlap": overlap,
         "throughput_rps": stats["completed"] / max(wall, 1e-9),
         "goodput_rps": on_time / max(wall, 1e-9),
         "on_time": int(on_time),
@@ -198,34 +189,4 @@ def bench_stress(scale: str = "quick", n_edge: int = 4,
                     f"saturation_rate={stages[sat]['offered_rate']:g}rps;"
                     f"peak_goodput={stages[sat]['goodput_rps']:.2f}rps")
 
-    # --- overlap A/B: identical overload stage, overlapped vs serial ---
-    # penultimate rung: saturated enough that stepping dominates, below
-    # the cap so walls stay comparable; best-of-2 filters scheduler noise
-    ab_rate = rates[-2] if len(rates) > 1 else rates[-1]
-    ab = {}
-    for overlap in (False, True):
-        best = None
-        for _ in range(2):
-            st = run_stage(engines, schedulers[0], rate=ab_rate,
-                           num_requests=stage_size(ab_rate),
-                           prompt_len=prompt_len, gen_tokens=gen_tokens,
-                           vocab=vocab, mix=mix, seed=seed + 9001,
-                           overlap=overlap)
-            if best is None or st["wall_s"] < best["wall_s"]:
-                best = st
-        ab["overlap" if overlap else "serial"] = best
-    speedup = (ab["serial"]["wall_s"] / max(ab["overlap"]["wall_s"], 1e-9))
-    records.append({
-        "bench": "stress_ab", "scheduler": schedulers[0],
-        "engines": n_edge, "offered_rate": float(ab_rate),
-        "serial_wall_s": ab["serial"]["wall_s"],
-        "overlap_wall_s": ab["overlap"]["wall_s"],
-        "overlap_speedup": speedup,
-        "serial_p95_s": ab["serial"]["p95_s"],
-        "overlap_p95_s": ab["overlap"]["p95_s"],
-    })
-    rows.append(f"stress_ab/{schedulers[0]}@{ab_rate:g}rps,0,"
-                f"serial={ab['serial']['wall_s']:.2f}s;"
-                f"overlap={ab['overlap']['wall_s']:.2f}s;"
-                f"speedup={speedup:.2f}x")
     return rows, records

@@ -63,8 +63,8 @@ def main():
     for policy in policies:
         for e in engines:
             e.reset()
-        cluster = EdgeCluster(engines, make_scheduler(policy, args.edges),
-                              qos_obs=args.qos)
+        cluster = EdgeCluster(engines, make_scheduler(policy, args.edges,
+                                                      qos=args.qos))
         trace = poisson_trace(args.requests, rate=args.rate,
                               prompt_len=args.prompt_len,
                               max_new_tokens=args.tokens,

@@ -5,21 +5,16 @@ the same Scheduler object (same carry, same trained weights) that drives
 the jitted episode scan here sees MEASURED per-engine backlogs and places
 real requests onto continuous-batching ``ServeEngine`` workers.
 
-The observation handed to the scheduler mirrors Eqn (6):
-``[d_n, workload_n, q_1..q_E]`` with d_n = prompt tokens, workload_n =
-requested generation length (the z_n quality demand), and q_e = engine
-backlog in pending tokens — each divided by a fixed scale so live
-features land in the same O(1) range the policies trained on.
-
-QoS-extended observation (``repro.workload``): when the scheduler was
-built for the wider ``[.., slack, c_1..c_E]`` row, the cluster appends
-the request's remaining deadline budget and a per-engine model-affinity
-feature — the request's expected decode seconds on each engine, from the
-engine's measured per-token rate (its live f_b'), inflated by
-``pref_penalty`` on engines whose arch differs from the request's
-``model_pref``.  The observation width is validated at CONSTRUCTION time
-against ``scheduler.state_dim``, so a policy trained on the wrong
-``EnvParams`` fails with a clear message instead of inside jit.
+The observation handed to the scheduler is a row in its own layout
+(``scheduler.layout``, see ``repro.core.obs``), each feature divided by
+a fixed scale so live features land in the O(1) range the policies
+trained on.  Base block (Eqn 6): prompt tokens d_n, requested generation
+length (the z_n demand) and each engine's backlog in pending tokens.
+QoS block: the remaining deadline budget and each engine's expected
+decode seconds for the request, from its measured per-token rate (its
+live f_b'), inflated by ``pref_penalty`` off the request's
+``model_pref`` arch.  Availability block: each engine's health (1 / 0.5
+degraded / 0 down).  Prefix block: its expected reusable prompt tokens.
 
 Fault tolerance (``repro.faults``): the cluster survives its engines.
 A :class:`~repro.faults.FaultInjector` drives scheduled crash / stall /
@@ -30,10 +25,8 @@ Requests orphaned by a crash — and everything still queued behind them —
 are re-offloaded through the scheduler with capped retries and
 exponential backoff; a per-request watchdog abandons requests whose
 deadline is hopeless, so overload sheds the starving best-effort tail
-instead of collapsing.  When fault observation is on, ``observe()``
-appends a NaN-guarded per-engine availability column (1 healthy /
-0.5 degraded / 0 down) so the same trained policy runs failure-aware in
-sim and live, and ``submit()`` masks selection away from DOWN engines.
+instead of collapsing.  ``observe()`` NaN-guards every row, and
+``submit()`` masks selection away from DOWN engines.
 """
 from __future__ import annotations
 
@@ -75,14 +68,9 @@ class EdgeCluster:
     def __init__(self, engines: Sequence, scheduler: Scheduler,
                  obs: Optional[LiveObsConfig] = None, seed: int = 0,
                  clock: Callable[[], float] = time.monotonic,
-                 qos_obs: Optional[bool] = None,
                  faults: Union[FaultInjector, Iterable[FaultEvent],
                                None] = None,
-                 retry: Optional[RetryPolicy] = None,
-                 fault_obs: Optional[bool] = None,
-                 prefix_obs: Optional[bool] = None,
-                 overlap: bool = True):
-        self.overlap = bool(overlap)
+                 retry: Optional[RetryPolicy] = None):
         if scheduler.num_engines != len(engines):
             raise ValueError(
                 f"scheduler targets {scheduler.num_engines} engines, "
@@ -92,11 +80,10 @@ class EdgeCluster:
             e.engine_id = i
         self.scheduler = scheduler
         self.obs = obs or LiveObsConfig()
-        E = len(self.engines)
 
         # fault machinery ------------------------------------------------
         if faults is not None and not isinstance(faults, FaultInjector):
-            faults = FaultInjector(list(faults), num_engines=E)
+            faults = FaultInjector(list(faults), num_engines=len(engines))
         self.injector: Optional[FaultInjector] = faults
         # the watchdog sheds only when the fault layer was asked for —
         # a fault-free cluster must behave exactly like the pre-fault one
@@ -109,88 +96,58 @@ class EdgeCluster:
                             "orphaned": 0, "retries": 0, "failed": 0,
                             "abandoned": 0, "orphan_recovery_s": []}
 
-        # observation width: (QoS, fault) feature combinations, plus an
-        # optional per-engine expected-prefix-hit block appended LAST
-        # (declared by the scheduler's ``prefix_obs`` class attribute)
-        base_dim, qos_dim = 2 + E, 3 + 2 * E
-        sched_dim = getattr(scheduler, "state_dim", None)
-        if prefix_obs is None:
-            prefix_obs = bool(getattr(scheduler, "prefix_obs", False))
-        self.prefix_obs = bool(prefix_obs)
-        # infer the QoS/fault layout from the width NET of the prefix block
-        eff_dim = (sched_dim - E if (sched_dim is not None
-                                     and self.prefix_obs) else sched_dim)
-        if qos_obs is None:
-            qos_obs = eff_dim in (qos_dim, qos_dim + E)
-        self.qos_obs = bool(qos_obs)
-        if fault_obs is None:
-            fault_obs = (eff_dim in (base_dim + E, qos_dim + E)
-                         if eff_dim is not None
-                         else self.injector is not None)
-        self.fault_obs = bool(fault_obs)
-        self.obs_dim = ((qos_dim if self.qos_obs else base_dim)
-                        + (E if self.fault_obs else 0)
-                        + (E if self.prefix_obs else 0))
-        if sched_dim is not None and sched_dim != self.obs_dim:
-            raise ValueError(
-                f"scheduler {scheduler.name!r} expects state_dim="
-                f"{sched_dim}, but this {E}-engine cluster produces "
-                f"{self.obs_dim}-feature observations "
-                f"({'QoS-extended 3+2E' if self.qos_obs else 'base 2+E'}"
-                f"{' + E availability' if self.fault_obs else ''}"
-                f"{' + E prefix-hit' if self.prefix_obs else ''}; "
-                f"base={base_dim}, extended={qos_dim}, +faults adds "
-                f"{E}, +prefix adds {E}).  Train the policy on an "
-                f"EnvParams with num_bs={E} and matching qos_mix / fault "
-                f"settings, or pass qos_obs= / fault_obs= / prefix_obs= "
-                f"explicitly.")
         self.carry = scheduler.init_carry()
         self._key = jax.random.key(seed)
         self._count = 0
         self._clock = clock
         self.n_max = int(getattr(scheduler, "n_max", 1))
 
+    @property
+    def obs_dim(self) -> int:
+        return self.scheduler.layout.dim
+
     # ------------------------------------------------------------------
     def observe(self, req: Request) -> jnp.ndarray:
-        """Eqn-6 style observation row for one arriving request."""
+        """Eqn-6 style observation row for one arriving request, in the
+        scheduler's layout."""
+        layout, o = self.scheduler.layout, self.obs
         q = np.asarray([e.pending_tokens for e in self.engines], np.float32)
-        prompt_len = req.prompt.shape[-1]
-        cols = [np.asarray([prompt_len / self.obs.d_scale,
-                            req.max_new_tokens / self.obs.w_scale],
-                           np.float32),
-                q / self.obs.q_scale]
-        if self.qos_obs:
+        blocks = dict(
+            d=np.asarray([req.prompt.shape[-1] / o.d_scale], np.float32),
+            w=np.asarray([req.max_new_tokens / o.w_scale], np.float32),
+            q=q / o.q_scale)
+        if layout.qos:
             budget = req.deadline_budget_s
             if budget is None:
-                slack = self.obs.slack_cap
+                slack = o.slack_cap
             else:
                 elapsed = (0.0 if req.t_arrival is None
                            else self._clock() - req.t_arrival)
-                slack = min(budget - elapsed, self.obs.slack_cap)
+                slack = min(budget - elapsed, o.slack_cap)
             aff = np.asarray([req.max_new_tokens * e.est_token_seconds
                               for e in self.engines], np.float32)
             if req.model_pref is not None:
                 mismatch = np.asarray(
                     [getattr(e, "arch_id", None) != req.model_pref
                      for e in self.engines])
-                aff = np.where(mismatch, aff * self.obs.pref_penalty, aff)
-            cols.append(np.asarray([slack / self.obs.slack_scale],
-                                   np.float32))
-            cols.append(aff / self.obs.c_scale)
-        if self.fault_obs:
-            cols.append(np.asarray([e.availability for e in self.engines],
-                                   np.float32))
-        if self.prefix_obs:
+                aff = np.where(mismatch, aff * o.pref_penalty, aff)
+            blocks.update(
+                slack=np.asarray([slack / o.slack_scale], np.float32),
+                aff=aff / o.c_scale)
+        if layout.fault:
+            blocks["avail"] = np.asarray(
+                [e.availability for e in self.engines], np.float32)
+        if layout.prefix:
             # expected reusable prompt tokens per engine RIGHT NOW — a
             # pure peek against each engine's prefix index; dense /
             # cache-off engines report 0
             hit = np.asarray(
                 [getattr(e, "expected_prefix_tokens", lambda r: 0)(req)
                  for e in self.engines], np.float32)
-            cols.append(hit / self.obs.hit_scale)
+            blocks["hit"] = hit / o.hit_scale
         # NaN-guard: a crashed engine mid-measurement must never poison
         # the policy input (inf backlog estimates, NaN EWMA rates)
-        row = np.nan_to_num(np.concatenate(cols), nan=0.0,
+        row = np.nan_to_num(layout.assemble(**blocks), nan=0.0,
                             posinf=np.finfo(np.float32).max / 2,
                             neginf=0.0)
         return jnp.asarray(row)
@@ -333,13 +290,12 @@ class EdgeCluster:
         """One cluster iteration; returns requests that reached a
         TERMINAL state this step (completed, failed, or abandoned).
 
-        Overlapped stepping (default): ALL engines' rounds are
-        dispatched before ANY engine's results are collected, so E
-        engines' prefill chunks + decode rounds execute concurrently on
-        device instead of serializing E blocking host round-trips — with
-        bit-identical tokens/statuses to ``overlap=False`` serial
-        stepping (each engine's dispatch->collect pair is exactly its
-        serial ``step()``; only the interleaving across engines changes).
+        ALL engines' rounds are dispatched before ANY engine's results
+        are collected, so E engines' prefill chunks + decode rounds
+        execute concurrently on device instead of serializing E blocking
+        host round-trips.  Each engine's dispatch->collect pair is
+        exactly its own ``step()``, so tokens and statuses are those of
+        each engine stepped alone; only the interleaving changes.
 
         Each engine's work is isolated: an exception quarantines that
         engine (DOWN, KV reclaimed, requests re-offloaded) instead of
@@ -355,15 +311,6 @@ class EdgeCluster:
             done += self._apply_faults(now_rel)
             done += self._flush_retries(now)
             done += self._shed_hopeless(now)
-        if not self.overlap:
-            for i, e in enumerate(self.engines):
-                if not e.available:
-                    continue
-                try:
-                    done += e.step()
-                except Exception as exc:  # noqa: BLE001 — quarantine all
-                    done += self._quarantine(i, exc)
-            return done
         for i, e in enumerate(self.engines):
             if not e.available:
                 continue

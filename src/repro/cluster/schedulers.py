@@ -1,7 +1,7 @@
 """Pluggable edge-cluster schedulers: one interface, two backends.
 
-A :class:`Scheduler` maps the paper's Eqn-6 observation rows
-``s = [d_n, rho_n*z_n, q_1..q_E]`` (normalised) to target-engine indices.
+A :class:`Scheduler` maps the paper's Eqn-6 observation rows, in the
+layout it is built with (``repro.core.obs``), to target-engine indices.
 Implementations are pure-JAX over an explicit ``carry`` pytree so ONE
 scheduler object can drive either
 
@@ -17,7 +17,7 @@ local-only) the paper ablates against.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,25 +25,37 @@ import numpy as np
 
 from repro import tracing
 from repro.core import agents as ag
+from repro.core.obs import ObsLayout
 from repro.core.trainer import LEARNED, make_agent_fns
 
 Carry = Any
 
 
 class Scheduler:
-    """Base: stateless-by-default scheduler over ``num_engines`` targets.
+    """Base: stateless-by-default scheduler over ``layout.num_engines``
+    targets.
 
-    ``state_dim`` declares the observation width the scheduler was built
-    for (``None`` = shape-agnostic).  ``EdgeCluster`` validates it at
-    construction: the base Eqn-6 row is ``2 + num_engines`` wide, the
-    QoS-extended row ``3 + 2 * num_engines`` (see ``repro.core.env``).
+    ``layout`` is the observation row the scheduler reads, fixed when it
+    is built: ``EdgeCluster`` builds its rows in it, and the simulator
+    checks that its environment's rows can be read as it.  ``needs``
+    names the blocks beyond the base row that ``select`` reads; a layout
+    without one of them is refused here.
     """
 
     name = "base"
-    state_dim: Optional[int] = None
+    needs: Tuple[str, ...] = ()
 
-    def __init__(self, num_engines: int):
-        self.num_engines = num_engines
+    def __init__(self, layout: ObsLayout):
+        missing = [b for b in self.needs if getattr(layout, b) is None]
+        if missing:
+            raise ValueError(f"scheduler {self.name!r} reads the "
+                             f"{', '.join(missing)} block of the row; "
+                             f"{layout} has none")
+        self.layout = layout
+
+    @property
+    def num_engines(self) -> int:
+        return self.layout.num_engines
 
     # -- JAX-traceable batch interface (one row per origin BS) -------------
     def init_carry(self) -> Carry:
@@ -51,7 +63,7 @@ class Scheduler:
 
     def select(self, carry: Carry, s: jnp.ndarray, n, key
                ) -> Tuple[jnp.ndarray, Carry]:
-        """s (B, state_dim) -> ((B,) int32 engine indices, carry)."""
+        """s (B, layout.dim) -> ((B,) int32 engine indices, carry)."""
         raise NotImplementedError
 
     # -- per-request interface for the live cluster ------------------------
@@ -63,19 +75,16 @@ class Scheduler:
     def select_one_masked(self, carry: Carry, s_row: jnp.ndarray,
                           origin: int, n: int, key,
                           avail) -> Tuple[int, Carry]:
-        """Availability-masked selection for the live cluster.
-
-        Default policy: take the scheduler's unmasked pick; if it landed
-        on a DOWN engine, redirect to the least-loaded available one
-        (reading the queue features at obs columns ``2:2+E``) — the live
-        twin of the simulator's ``repro.faults.mask_actions``.  The
-        caller guarantees at least one engine is available.
-        """
+        """Availability-masked selection for the live cluster: the
+        unmasked pick, or, if it landed on a DOWN engine, the available
+        one with the least queue — the live twin of the simulator's
+        ``repro.faults.mask_actions``.  The caller guarantees at least
+        one engine is available."""
         eng, carry = self.select_one(carry, s_row, origin, n, key)
         avail = np.asarray(avail, bool)
         if avail[eng]:
             return eng, carry
-        q = np.asarray(s_row, np.float32)[2:2 + self.num_engines]
+        q = np.asarray(s_row, np.float32)[self.layout.q]
         return int(np.argmin(np.where(avail, q, np.inf))), carry
 
 
@@ -97,12 +106,12 @@ class RandomScheduler(Scheduler):
 
 
 class JoinShortestQueueScheduler(Scheduler):
-    """Pick the engine with the smallest queue feature (obs columns 2:)."""
+    """Pick the engine with the smallest queue feature."""
 
     name = "jsq"
 
     def select(self, carry, s, n, key):
-        q = s[:, 2:2 + self.num_engines]
+        q = s[:, self.layout.q]
         return jnp.argmin(q, axis=-1).astype(jnp.int32), carry
 
 
@@ -122,119 +131,88 @@ class LocalOnlyScheduler(Scheduler):
 class DeadlineAwareScheduler(Scheduler):
     """Earliest-expected-completion placement on the QoS observation.
 
-    Requires the extended row ``[d, w, q_1..q_E, slack, c_1..c_E]``:
-    picks the engine minimising backlog + this task's own expected
-    compute there (``q_e + c_e``) — JSQ that actually accounts for
-    heterogeneous model/engine speed.  Per-request deadline URGENCY is
-    handled where it belongs, in the engines' priority/EDF queues; this
-    placement rule maximises the chance the slack survives the queue.
+    Needs the QoS block ``[slack, c_1..c_E]``: picks the engine
+    minimising backlog + this task's own expected compute there
+    (``q_e + c_e``) — JSQ that actually accounts for heterogeneous
+    model/engine speed.  Per-request deadline URGENCY is handled where it
+    belongs, in the engines' priority/EDF queues; this placement rule
+    maximises the chance the slack survives the queue.
     """
 
     name = "deadline"
-
-    def __init__(self, num_engines: int):
-        super().__init__(num_engines)
-        self.state_dim = 3 + 2 * num_engines
+    needs = ("aff",)
 
     def select(self, carry, s, n, key):
-        E = self.num_engines
-        q = s[:, 2:2 + E]
-        aff = s[:, 3 + E:3 + 2 * E]
-        return jnp.argmin(q + aff, axis=-1).astype(jnp.int32), carry
+        cost = s[:, self.layout.q] + s[:, self.layout.aff]
+        return jnp.argmin(cost, axis=-1).astype(jnp.int32), carry
+
+
+def _least_work(layout: ObsLayout, s: jnp.ndarray) -> jnp.ndarray:
+    """Backlog (+ this task's compute with the QoS block); with the
+    availability block DOWN engines cost inf, degraded (0.5) ones 2x."""
+    cost = s[:, layout.q]
+    if layout.aff is not None:
+        cost = cost + s[:, layout.aff]
+    if layout.avail is not None:
+        avail = s[:, layout.avail]
+        cost = cost / jnp.maximum(avail, 0.5)
+        cost = jnp.where(avail > 0.25, cost, jnp.inf)
+    return cost
 
 
 class FailureAwareScheduler(Scheduler):
     """Availability-masked least-work placement on a fault-extended row.
 
-    Requires fault observation: the row's trailing ``E`` columns are the
-    per-engine availability features (1 healthy / 0.5 degraded / 0 down)
-    appended by ``EdgeCluster.observe`` and by the fault-enabled
-    ``core.env`` scan.  Placement is JSQ over the AVAILABLE engines —
-    plus this task's expected compute there when the QoS affinity
-    columns are present — and DOWN engines are hard-masked, so it never
+    Needs the availability block (1 healthy / 0.5 degraded / 0 down per
+    engine), which ``EdgeCluster.observe`` and the fault-enabled
+    ``core.env`` scan both fill.  Placement is JSQ over the AVAILABLE
+    engines — plus this task's expected compute there when the layout
+    has the QoS block — and DOWN engines are hard-masked, so it never
     pays the simulator's wrong-choice penalty and never strands a live
     request on a dead server.  DEGRADED engines stay eligible but their
     0.5 availability halves their attractiveness via a load inflation.
     """
 
     name = "failure-aware"
-
-    def __init__(self, num_engines: int, qos: bool = False):
-        super().__init__(num_engines)
-        self.qos = bool(qos)
-        base = 3 + 2 * num_engines if qos else 2 + num_engines
-        self.state_dim = base + num_engines
+    needs = ("avail",)
 
     def select(self, carry, s, n, key):
-        E = self.num_engines
-        cost = s[:, 2:2 + E]
-        if self.qos:
-            cost = cost + s[:, 3 + E:3 + 2 * E]
-        avail = s[:, -E:]
-        # degraded (0.5) engines serve at reduced rate: scale their cost
-        cost = cost / jnp.maximum(avail, 0.5)
-        cost = jnp.where(avail > 0.25, cost, jnp.inf)
         # all-down column of inf -> argmin returns 0; the live cluster
         # never reaches that case (submit refuses on a total outage)
+        cost = _least_work(self.layout, s)
         return jnp.argmin(cost, axis=-1).astype(jnp.int32), carry
 
 
 class PrefixAffinityScheduler(Scheduler):
     """Cache-aware placement: route where the prompt's KV already lives.
 
-    Declares ``prefix_obs = True``, so ``EdgeCluster.observe`` appends a
-    trailing per-engine block of EXPECTED PREFIX HITS — how many of this
-    request's prompt tokens each engine could serve straight from its
-    prefix cache (a pure peek; dense engines report 0).  Placement then
-    minimises backlog minus a cache credit::
+    Needs the prefix block: per engine, the EXPECTED PREFIX HITS — how
+    many of this request's prompt tokens it could serve straight from
+    its prefix cache (a pure peek; dense engines report 0).  Placement
+    then minimises backlog minus a cache credit::
 
-        cost_e = q_e [+ c_e with qos=True] - hit_weight * hit_e
+        cost_e = q_e [+ c_e with the QoS block] - hit_weight * hit_e
 
     i.e. earliest-expected-completion where compute ALREADY DONE at an
     engine counts as negative work — the paper's "finish fastest" rule
     once resident state is part of an engine's effective speed.  The
     credit concentrates same-prefix requests on warm engines (raising
     their hit rate further), while the backlog term keeps a hot prefix
-    from melting one engine.  With ``fault=True`` the availability
-    columns (just before the hit block) mask DOWN engines exactly like
-    ``failure-aware``.
+    from melting one engine.  With the availability block DOWN engines
+    are masked exactly like ``failure-aware``.
     """
 
     name = "prefix-affinity"
-    prefix_obs = True
+    needs = ("hit",)
 
-    def __init__(self, num_engines: int, qos: bool = False,
-                 fault: bool = False, hit_weight: float = 0.5):
-        super().__init__(num_engines)
-        self.qos = bool(qos)
-        self.fault = bool(fault)
+    def __init__(self, layout: ObsLayout, hit_weight: float = 0.5):
+        super().__init__(layout)
         self.hit_weight = float(hit_weight)
-        base = 3 + 2 * num_engines if self.qos else 2 + num_engines
-        self.state_dim = (base + (num_engines if self.fault else 0)
-                          + num_engines)
 
     def select(self, carry, s, n, key):
-        E = self.num_engines
-        cost = s[:, 2:2 + E]
-        if self.qos:
-            cost = cost + s[:, 3 + E:3 + 2 * E]
-        hit = s[:, -E:]
-        if self.fault:
-            avail = s[:, -2 * E:-E]
-            cost = cost / jnp.maximum(avail, 0.5)
-            cost = jnp.where(avail > 0.25, cost, jnp.inf)
-        cost = cost - self.hit_weight * hit
+        cost = (_least_work(self.layout, s)
+                - self.hit_weight * s[:, self.layout.hit])
         return jnp.argmin(cost, axis=-1).astype(jnp.int32), carry
-
-
-def _infer_state_dim(states) -> Optional[int]:
-    """Observation width a stacked agent pytree was trained on (the
-    second-to-last axis of the first critic/Q layer's weights)."""
-    for attr in ("c1", "q"):
-        net = getattr(states, attr, None)
-        if net is not None:
-            return int(net[0]["w"].shape[-2])
-    return None
 
 
 class PolicyScheduler(Scheduler):
@@ -242,23 +220,31 @@ class PolicyScheduler(Scheduler):
 
     ``states`` is the per-BS *stacked* agent pytree exactly as returned by
     ``repro.core.trainer.train_method`` — one agent per origin BS, vmapped
-    for batch decisions (the paper's distributed deployment).  The latent
+    for batch decisions (the paper's distributed deployment) — and
+    ``layout`` the ``EnvParams.layout`` it was trained on.  The latent
     action store (LAD-TS) keeps evolving inside the carry, so serving
     decisions keep self-conditioning the diffusion chain.
     """
 
     def __init__(self, method: str, cfg: ag.AgentConfig, states,
-                 num_engines: int, n_max: int, greedy: bool = False):
+                 layout: ObsLayout, n_max: int, greedy: bool = False):
         if method not in LEARNED:
             raise ValueError(f"{method!r} is not a learned method")
-        super().__init__(num_engines)
+        # input width of the first critic (Q-net for DQN) layer
+        net = states.q if method == "dqn-ts" else states.c1
+        width = int(net[0]["w"].shape[-2])
+        if width != layout.dim:
+            raise ValueError(
+                f"{method} weights read {width}-wide rows, but {layout} "
+                f"is {layout.dim} wide: train on an EnvParams whose "
+                f"layout this is")
+        super().__init__(layout)
         self.name = method
         self.method = method
         self.cfg = cfg
         self.states = states
         self.n_max = int(n_max)
         self.greedy = greedy
-        self.state_dim = _infer_state_dim(states)
         _, act, _, _, _ = make_agent_fns(method, cfg)
         self._act = act
         self._vact = jax.vmap(act, in_axes=(0, 0, None, 0, None))
@@ -291,37 +277,31 @@ class PolicyScheduler(Scheduler):
         return a, carry
 
 
-BASELINES = ("round-robin", "jsq", "random", "local", "deadline",
-             "failure-aware", "prefix-affinity")
+_BASELINES = {c.name: c for c in (
+    RoundRobinScheduler, JoinShortestQueueScheduler, RandomScheduler,
+    LocalOnlyScheduler, DeadlineAwareScheduler, FailureAwareScheduler,
+    PrefixAffinityScheduler)}
+BASELINES = tuple(_BASELINES)
 
 
-def make_scheduler(name: str, num_engines: int, **policy_kwargs) -> Scheduler:
-    """Factory: baseline by name, or a learned method given agent states.
+def make_scheduler(name: str, num_engines: int, qos: bool = False,
+                   fault: bool = False, **kwargs) -> Scheduler:
+    """Factory: a baseline, or a learned method given agent states, built
+    on ``ObsLayout(num_engines, qos, fault)``.
 
-    ``failure-aware`` accepts ``qos=True`` to read the QoS-extended row;
-    ``prefix-affinity`` additionally accepts ``fault=True`` and
-    ``hit_weight=`` (cache-credit strength).
+    A baseline's own blocks join the layout: ``deadline`` reads the QoS
+    block, ``failure-aware`` the availability block, ``prefix-affinity``
+    the prefix block.  ``prefix-affinity`` also takes ``hit_weight=``
+    (cache-credit strength); learned methods take ``PolicyScheduler``'s
+    ``cfg``, ``states`` and ``n_max``.
     """
-    if name == "round-robin":
-        return RoundRobinScheduler(num_engines)
-    if name == "jsq":
-        return JoinShortestQueueScheduler(num_engines)
-    if name == "random":
-        return RandomScheduler(num_engines)
-    if name == "local":
-        return LocalOnlyScheduler(num_engines)
-    if name == "deadline":
-        return DeadlineAwareScheduler(num_engines)
-    if name == "failure-aware":
-        return FailureAwareScheduler(num_engines,
-                                     qos=policy_kwargs.pop("qos", False))
-    if name == "prefix-affinity":
-        return PrefixAffinityScheduler(
-            num_engines, qos=policy_kwargs.pop("qos", False),
-            fault=policy_kwargs.pop("fault", False),
-            hit_weight=policy_kwargs.pop("hit_weight", 0.5))
     if name in LEARNED:
-        return PolicyScheduler(name, num_engines=num_engines,
-                               **policy_kwargs)
-    raise ValueError(f"unknown scheduler {name!r}; options: "
-                     f"{BASELINES + LEARNED}")
+        return PolicyScheduler(name, layout=ObsLayout(num_engines, qos,
+                                                      fault), **kwargs)
+    if name not in _BASELINES:
+        raise ValueError(f"unknown scheduler {name!r}; options: "
+                         f"{BASELINES + LEARNED}")
+    layout = ObsLayout(num_engines, qos=qos or name == "deadline",
+                       fault=fault or name == "failure-aware",
+                       prefix=name == "prefix-affinity")
+    return _BASELINES[name](layout, **kwargs)

@@ -40,7 +40,13 @@ def build_sim_episode(scheduler: Scheduler, p: envlib.EnvParams) -> Callable:
     availability vector rides the carry as inert ones and every computed
     quantity (observations, RNG stream, delays) is bit-identical to the
     legacy scan.
+
+    Raises ``ValueError`` when the scheduler's layout cannot read
+    ``p.layout``'s rows (a block it reads sits elsewhere or is absent).
     """
+    if not p.layout.reads(scheduler.layout):
+        raise ValueError(f"scheduler {scheduler.name!r} reads "
+                         f"{scheduler.layout}; the env's rows are {p.layout}")
     scale = envlib.state_scale(p)
 
     def episode(carry, ep: envlib.EpisodeData, key):

@@ -31,6 +31,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.obs import ObsLayout
 # re-exported so env consumers drive the fault process through envlib
 from repro.faults.simfault import (FaultParams, init_avail,  # noqa: F401
                                    mask_actions, step_avail)
@@ -44,20 +45,18 @@ class EnvParams:
     ``repro.workload.qos``) switches on the heterogeneous-QoS extension:
     each task additionally samples a service class (per-class quality
     demand z_n, deadline budget, priority weight), the observation grows
-    deadline-slack and per-ES model-affinity features (state layout
-    ``[d, rho*z, q_1..q_B, slack, rho*z/f_1..rho*z/f_B]``), rewards are
-    priority-weighted, and ``deadline_penalty`` optionally adds a miss
-    penalty to Eqn (9).  With an empty mix everything reduces exactly to
-    the paper's setup.
+    the QoS block (``repro.core.obs``), rewards are priority-weighted, and
+    ``deadline_penalty`` optionally adds a miss penalty to Eqn (9).  With
+    an empty mix everything reduces exactly to the paper's setup.
 
     ``fault`` (a :class:`repro.faults.FaultParams`) switches on the
     availability extension: every ES runs an independent Bernoulli
     up/down chain inside the episode scan, DOWN servers stop draining
-    (Eqn 4's ``f`` term gated), the observation grows per-ES
-    availability columns ``[.., a_1..a_B]`` appended LAST, and actions
-    landing on a DOWN server are remapped to the least-loaded available
-    one with ``penalty_s`` added to the task's delay.  ``fault=None``
-    reproduces the legacy environment bit-for-bit, same as ``qos_mix``.
+    (Eqn 4's ``f`` term gated), the observation grows the availability
+    block, and actions landing on a DOWN server are remapped to the
+    least-loaded available one with ``penalty_s`` added to the task's
+    delay.  ``fault=None`` reproduces the legacy environment
+    bit-for-bit, same as ``qos_mix``.
     """
 
     num_bs: int = 20                 # B
@@ -106,13 +105,16 @@ class EnvParams:
         return float(z)
 
     @property
+    def layout(self) -> ObsLayout:
+        """The observation row (``repro.core.obs``): Eqn 6's
+        ``[d_n, rho_n * z_n, q_{t-1,1..B}]``, plus the QoS block when
+        classes are active and the availability block when faults are."""
+        return ObsLayout(self.num_bs, qos=self.has_qos,
+                         fault=self.has_faults)
+
+    @property
     def state_dim(self) -> int:
-        # s = [d_n, rho_n * z_n, q_{t-1,1..B}]  (Eqn 6)
-        # + [slack, rho_n * z_n / f_1..B] when QoS classes are active
-        # + [a_1..B] availability (appended LAST) when faults are active
-        base = 2 + self.num_bs
-        return (base + (1 + self.num_bs if self.has_qos else 0)
-                + (self.num_bs if self.has_faults else 0))
+        return self.layout.dim
 
     @property
     def action_dim(self) -> int:
@@ -216,35 +218,30 @@ def init_queues(p: EnvParams) -> QueueState:
 
 def observe(p: EnvParams, qs: QueueState, d, workload,
             slack=None, f=None, avail=None) -> jnp.ndarray:
-    """Per-task state vector (Eqn 6), vectorised over the B stations.
+    """Per-task rows (Eqn 6) in ``p.layout``, vectorised over the B
+    stations.  d, workload: (B,) — the n-th task of each BS.  Returns
+    (B, state_dim).
 
-    d, workload: (B,) — the n-th task of each BS.  Returns (B, state_dim).
-
-    With QoS enabled the row is extended by a deadline-slack scalar
-    (remaining budget, clamped at ``slack_cap``) and per-ES affinity
-    features ``workload / f_b'`` — the task's expected compute seconds on
-    each target, which is what makes heterogeneous capacities visible to
-    the policy before queues build up.
-
-    With faults enabled the row additionally carries the per-ES
-    availability vector (appended LAST, matching the live cluster) so a
-    policy can learn to steer around DOWN servers.
+    With QoS the rows add the deadline slack (clamped at ``slack_cap``)
+    and each ES's expected compute seconds ``workload / f_b'``, which
+    make heterogeneous capacities visible to the policy before queues
+    build up; with faults, the per-ES availability.
     """
-    qrep = jnp.broadcast_to(qs.q_prev[None, :], (p.num_bs, p.num_bs))
-    cols = [d[:, None], workload[:, None], qrep]
+    B = p.num_bs
+    blocks = dict(d=d[:, None], w=workload[:, None],
+                  q=jnp.broadcast_to(qs.q_prev[None, :], (B, B)))
     if p.has_qos:
         if slack is None or f is None:
             raise ValueError("QoS-enabled EnvParams: observe() needs the "
                              "per-task deadline slack and capacities f")
-        cols.append(jnp.minimum(slack, p.slack_cap)[:, None])
-        cols.append(workload[:, None] / f[None, :])
+        blocks.update(slack=jnp.minimum(slack, p.slack_cap)[:, None],
+                      aff=workload[:, None] / f[None, :])
     if p.has_faults:
         if avail is None:
             raise ValueError("fault-enabled EnvParams: observe() needs "
                              "the per-ES availability vector")
-        cols.append(jnp.broadcast_to(avail[None, :],
-                                     (p.num_bs, p.num_bs)))
-    return jnp.concatenate(cols, axis=1)
+        blocks["avail"] = jnp.broadcast_to(avail[None, :], (B, B))
+    return p.layout.assemble(**blocks)
 
 
 def task_delays(p: EnvParams, ep: EpisodeData, qs: QueueState, t, n,
@@ -291,14 +288,13 @@ def state_scale(p: EnvParams) -> jnp.ndarray:
     d_hi = p.d_range[1]
     w_hi = p.rho_range[1] * p.z_hi
     q_hi = p.rho_range[1] * p.z_hi * p.max_tasks  # rough slot load
-    parts = [
-        jnp.array([d_hi, w_hi], jnp.float32),
-        jnp.full((p.num_bs,), q_hi, jnp.float32),
-    ]
+    B = p.num_bs
+    blocks = dict(d=jnp.array([d_hi], jnp.float32),
+                  w=jnp.array([w_hi], jnp.float32),
+                  q=jnp.full((B,), q_hi, jnp.float32))
     if p.has_qos:
-        parts.append(jnp.array([p.slack_cap], jnp.float32))
-        parts.append(jnp.full((p.num_bs,), w_hi / p.f_range[0],
-                              jnp.float32))
+        blocks.update(slack=jnp.array([p.slack_cap], jnp.float32),
+                      aff=jnp.full((B,), w_hi / p.f_range[0], jnp.float32))
     if p.has_faults:
-        parts.append(jnp.ones((p.num_bs,), jnp.float32))
-    return jnp.concatenate(parts)
+        blocks["avail"] = jnp.ones((B,), jnp.float32)
+    return p.layout.assemble(**blocks)

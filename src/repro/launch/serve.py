@@ -57,12 +57,8 @@ def build_scheduler(name: str, n_edge: int, train_episodes: int, seed: int,
     if name == "deadline" and not qos:
         raise SystemExit("--scheduler deadline needs the QoS-extended "
                          "observation; pass --qos")
-    if name == "failure-aware":
-        return make_scheduler(name, n_edge, qos=qos)
-    if name == "prefix-affinity":
-        return make_scheduler(name, n_edge, qos=qos, fault=chaos)
     if name in BASELINES:
-        return make_scheduler(name, n_edge)
+        return make_scheduler(name, n_edge, qos=qos, fault=chaos)
     if name not in LEARNED:
         raise SystemExit(f"unknown scheduler {name!r}; options: "
                          f"{', '.join(BASELINES + LEARNED)}")
@@ -76,8 +72,7 @@ def build_scheduler(name: str, n_edge: int, train_episodes: int, seed: int,
           f"{', fault-enabled' if chaos else ''})...")
     _, states = train_method(name, p, acfg, episodes=train_episodes,
                              key=jax.random.key(seed))
-    return PolicyScheduler(name, acfg, states, num_engines=n_edge,
-                           n_max=p.max_tasks)
+    return PolicyScheduler(name, acfg, states, p.layout, n_max=p.max_tasks)
 
 
 def main():
@@ -149,7 +144,7 @@ def main():
             print(f"[serve] fault @{ev['t_s']:.2f}s engine={ev['engine']} "
                   f"{ev['kind']}")
     cluster = EdgeCluster(engines, scheduler, seed=args.seed,
-                          qos_obs=args.qos, faults=injector, retry=retry)
+                          faults=injector, retry=retry)
     trace = poisson_trace(args.requests, rate=args.rate,
                           prompt_len=args.prompt_len,
                           max_new_tokens=args.tokens, vocab_size=vocab,

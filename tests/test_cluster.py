@@ -143,7 +143,7 @@ def test_pending_tokens_tracks_backlog():
 def _schedulers():
     _, states = train_method("lad-ts", ENV, ACFG, episodes=1, key=KEY)
     return {
-        "lad-ts": PolicyScheduler("lad-ts", ACFG, states, num_engines=2,
+        "lad-ts": PolicyScheduler("lad-ts", ACFG, states, ENV.layout,
                                   n_max=ENV.max_tasks),
         "jsq": make_scheduler("jsq", 2),
         "round-robin": make_scheduler("round-robin", 2),

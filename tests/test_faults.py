@@ -7,11 +7,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.cluster import (EdgeCluster, Request, evaluate_scheduler,
+from repro.cluster import (EdgeCluster, FailureAwareScheduler, Request,
+                           build_sim_episode, evaluate_scheduler,
                            make_scheduler, poisson_trace, summarize)
 from repro.configs import get_config, reduced
 from repro.core.env import EnvParams
 from repro.core import env as envlib
+from repro.core.obs import ObsLayout
 from repro.faults import (FaultEvent, FaultInjector, FaultParams, FaultSpec,
                           Health, RetryPolicy, single_crash)
 from repro.models.transformer import init_params
@@ -448,16 +450,23 @@ def test_live_observation_appends_availability_and_nan_guards():
     engines, _ = _cluster_pair()
     sched = make_scheduler("failure-aware", 2)
     cluster = EdgeCluster(engines, sched)
-    assert cluster.fault_obs and not cluster.qos_obs
+    assert sched.layout == ObsLayout(2, fault=True)
     engines[1].fail("test")
     row = np.asarray(cluster.observe(_req(0, _prompt(engines[0]))))
-    assert row.shape == (cluster.obs_dim,)
-    np.testing.assert_array_equal(row[-2:], [1.0, 0.0])
+    assert row.shape == (cluster.obs_dim,) == (sched.layout.dim,)
+    np.testing.assert_array_equal(row[sched.layout.avail], [1.0, 0.0])
     assert np.isfinite(row).all()
 
 
 def test_state_dim_mismatch_message_mentions_faults():
-    engines = [_engine(paged=False, kv_slots=1, seed=i) for i in range(2)]
-    sched = make_scheduler("failure-aware", 2)
-    with pytest.raises(ValueError, match="state_dim"):
-        EdgeCluster(engines, sched, fault_obs=False)
+    """failure-aware refuses a layout without the availability block, and
+    the simulator refuses a scheduler whose availability columns sit
+    elsewhere in its rows (here after a QoS block it does not expect)."""
+    with pytest.raises(ValueError, match="avail"):
+        FailureAwareScheduler(ObsLayout(2, qos=True))
+    p = EnvParams(num_bs=2, num_slots=2, max_tasks=2,
+                  qos_mix=((INTERACTIVE, 1.0),), fault=FaultParams())
+    with pytest.raises(ValueError, match="failure-aware"):
+        build_sim_episode(make_scheduler("failure-aware", 2), p)
+    # built on the environment's own layout it reads the rows
+    build_sim_episode(make_scheduler("failure-aware", 2, qos=True), p)

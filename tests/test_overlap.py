@@ -45,18 +45,11 @@ def _drain(cluster, n):
     return done
 
 
-def _run_trace(overlap, seed0=3):
-    """Submit an identical burst into an identical fresh fleet and drain."""
-    engines = _mixed_fleet(seed0)
-    cluster = EdgeCluster(engines, make_scheduler("jsq", len(engines)),
-                          seed=11, overlap=overlap)
-    trace = poisson_trace(8, rate=1e9, prompt_len=10, max_new_tokens=5,
-                          vocab_size=min(e.cfg.vocab_size for e in engines),
-                          num_origins=len(engines), seed=5)
-    for r in trace:
-        cluster.submit(r)
-    done = _drain(cluster, len(trace))
-    return engines, {r.rid: r for r in done}
+def _burst(engines):
+    """An 8-request burst; the same seed gives the same requests anew."""
+    return poisson_trace(8, rate=1e9, prompt_len=10, max_new_tokens=5,
+                         vocab_size=min(e.cfg.vocab_size for e in engines),
+                         num_origins=len(engines), seed=5)
 
 
 # ---------------------------------------------------------------------------
@@ -65,11 +58,25 @@ def _run_trace(overlap, seed0=3):
 
 
 def test_overlap_parity_with_serial_stepping():
-    """Same burst through overlap=False and overlap=True clusters over a
-    mixed paged+dense fleet: tokens bit-identical, same terminal statuses,
-    ordered timestamps, and no leaked KV reservations."""
-    eng_serial, serial = _run_trace(overlap=False)
-    eng_overlap, overlap = _run_trace(overlap=True)
+    """The same burst through the overlapped cluster, and through each
+    engine's own serial ``step()`` on an identical fresh mixed
+    paged+dense fleet (every request on the engine the cluster chose, in
+    arrival order): tokens bit-identical, same terminal statuses, ordered
+    timestamps, and no leaked KV reservations."""
+    engines = _mixed_fleet()
+    cluster = EdgeCluster(engines, make_scheduler("jsq", len(engines)),
+                          seed=11)
+    trace = _burst(engines)
+    for r in trace:
+        cluster.submit(r)
+    overlap = {r.rid: r for r in _drain(cluster, len(trace))}
+    assert len({r.engine_id for r in overlap.values()}) > 1
+
+    eng_serial = _mixed_fleet()
+    for r in _burst(eng_serial):
+        eng_serial[overlap[r.rid].engine_id].admit(r)
+    serial = {r.rid: r for e in eng_serial
+              for r in e.run_to_completion()}
     assert serial.keys() == overlap.keys() and len(serial) == 8
     for rid, a in serial.items():
         b = overlap[rid]
@@ -79,7 +86,7 @@ def test_overlap_parity_with_serial_stepping():
         assert np.array_equal(ta, tb), f"rid {rid}: token divergence"
         assert b.t_enqueue <= b.t_prefill_start <= b.t_prefill_end \
             <= b.t_finish
-    for e in eng_serial + eng_overlap:
+    for e in eng_serial + engines:
         assert e.kv_leak == 0
         assert not e.has_work
 

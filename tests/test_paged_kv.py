@@ -693,28 +693,32 @@ def test_prefix_affinity_routes_to_warm_engine():
     _run_one(engines[1], prompt, rid=0)        # warm ONLY engine 1
     sched = make_scheduler("prefix-affinity", 2)
     cluster = EdgeCluster(engines, sched)
-    assert cluster.prefix_obs
+    assert sched.layout.prefix
     assert cluster.obs_dim == 2 + 2 * 2        # base (2+E) + hit block E
     req = Request(rid=1, prompt=prompt, max_new_tokens=2, arrival_s=0.0)
     row = np.asarray(cluster.observe(req))
     assert row.shape == (cluster.obs_dim,)
-    assert row[-1] > row[-2] == 0.0            # hit feature: engine 1 only
+    hit = row[sched.layout.hit]
+    assert hit[1] > hit[0] == 0.0              # hit feature: engine 1 only
     done = cluster.run([req])
     assert done[0].engine_id == 1
     assert done[0].prefix_tokens > 0
 
 
 def test_prefix_affinity_state_dim_guard():
-    """Suppressing the prefix block (and the fault layout it would alias
-    into: base+E == 6 too) must fail construction with a message that
-    names the prefix extension."""
-    from repro.cluster import EdgeCluster, make_scheduler
-    from repro.serving.builders import build_engines
-    engines = build_engines("qwen2-1.5b", 2, max_len=48, kv_slots=2,
-                            depths=[2, 2], page_size=8, prefill_chunk=8)
-    sched = make_scheduler("prefix-affinity", 2)
-    with pytest.raises(ValueError, match="prefix"):
-        EdgeCluster(engines, sched, prefix_obs=False, fault_obs=False)
+    """prefix-affinity refuses a layout without the prefix block, and the
+    simulator, whose rows have none, refuses prefix-affinity."""
+    from repro.cluster import (PrefixAffinityScheduler, build_sim_episode,
+                               make_scheduler)
+    from repro.core.env import EnvParams
+    from repro.core.obs import ObsLayout
+    with pytest.raises(ValueError, match="hit"):
+        PrefixAffinityScheduler(ObsLayout(2, fault=True))
+    sched = make_scheduler("prefix-affinity", 2, fault=True)
+    assert sched.layout == ObsLayout(2, fault=True, prefix=True)
+    with pytest.raises(ValueError, match="prefix-affinity"):
+        build_sim_episode(sched, EnvParams(num_bs=2, num_slots=2,
+                                           max_tasks=2))
 
 
 def test_poisson_trace_shared_prefix_and_stream_identity():

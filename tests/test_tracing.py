@@ -10,6 +10,7 @@ from repro import tracing
 from repro.cluster import (EdgeCluster, PolicyScheduler, Request,
                            make_scheduler)
 from repro.configs import get_config, reduced
+from repro.core.obs import ObsLayout
 from repro.models.transformer import init_params
 from repro.serving.engine import ServeEngine
 
@@ -186,7 +187,7 @@ def test_cluster_scheduler_and_engine_spans_follow_the_layers():
 def _fake_policy(num_engines=2):
     """A PolicyScheduler whose compiled select always picks engine 1."""
     s = PolicyScheduler.__new__(PolicyScheduler)
-    s.num_engines, s.n_max, s.greedy = num_engines, 1, True
+    s.layout, s.n_max, s.greedy = ObsLayout(num_engines), 1, True
     s.states = jnp.zeros((), jnp.int32)
     s._sel1 = jax.jit(lambda carry, row, origin, n, key:
                       (jnp.int32(1), carry))

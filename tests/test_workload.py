@@ -8,9 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.cluster import (DeadlineAwareScheduler, EdgeCluster,
-                           PolicyScheduler, Request, evaluate_scheduler,
-                           make_scheduler, poisson_trace, summarize)
+from repro.cluster import (EdgeCluster, PolicyScheduler, Request,
+                           evaluate_scheduler, make_scheduler,
+                           poisson_trace, summarize)
 from repro.configs import get_config, reduced
 from repro.core.agents import AgentConfig
 from repro.core.diffusion import DiffusionPolicyConfig
@@ -262,8 +262,8 @@ def test_env_without_qos_unchanged():
 
 
 def test_deadline_scheduler_picks_min_queue_plus_affinity():
-    s = DeadlineAwareScheduler(3)
-    assert s.state_dim == 3 + 2 * 3
+    s = make_scheduler("deadline", 3)
+    assert s.layout.dim == 3 + 2 * 3
     #        d    w    q1   q2   q3  slack aff1 aff2 aff3
     row = [0.5, 0.5, 0.9, 0.1, 0.5, 1.0, 0.0, 0.9, 0.1]
     a, _ = s.select_one(s.init_carry(), jnp.asarray(row), 0, 0,
@@ -273,7 +273,7 @@ def test_deadline_scheduler_picks_min_queue_plus_affinity():
 
 def test_deadline_scheduler_in_qos_sim():
     p = EnvParams(num_bs=2, num_slots=3, max_tasks=3, qos_mix=DEFAULT_MIX)
-    r = evaluate_scheduler(DeadlineAwareScheduler(2), p, episodes=1,
+    r = evaluate_scheduler(make_scheduler("deadline", 2), p, episodes=1,
                            key=jax.random.key(1))
     assert r["count"] > 0 and r["mean_s"] > 0
     assert 0.0 <= r["deadline_miss_rate"] <= 1.0
@@ -284,27 +284,29 @@ def test_deadline_scheduler_in_qos_sim():
 
 
 def test_policy_state_dim_inferred_and_validated():
-    """A policy trained on the base observation must be rejected by a
-    QoS-observing cluster at construction time, with a clear message."""
+    """A policy is built on the layout it was trained on: weights of any
+    other width are refused at construction with both widths named, and
+    the live cluster's rows follow the scheduler's layout."""
     base = EnvParams(num_bs=2, num_slots=2, max_tasks=2)
     qos = dataclasses.replace(base, qos_mix=DEFAULT_MIX)
+    engines = [_engine(seed=0), _engine(seed=1)]
     for method in ("lad-ts", "dqn-ts"):
         st_base = init_agents(method, base, ACFG, jax.random.key(0))
         st_qos = init_agents(method, qos, ACFG, jax.random.key(0))
-        s_base = PolicyScheduler(method, ACFG, st_base, num_engines=2,
+        with pytest.raises(ValueError, match="4-wide.* 7 wide"):
+            PolicyScheduler(method, ACFG, st_base, qos.layout, n_max=2)
+        with pytest.raises(ValueError, match="7-wide.* 4 wide"):
+            PolicyScheduler(method, ACFG, st_qos, base.layout, n_max=2)
+        s_base = PolicyScheduler(method, ACFG, st_base, base.layout,
                                  n_max=base.max_tasks)
-        s_qos = PolicyScheduler(method, ACFG, st_qos, num_engines=2,
+        s_qos = PolicyScheduler(method, ACFG, st_qos, qos.layout,
                                 n_max=qos.max_tasks)
-        assert s_base.state_dim == base.state_dim == 4
-        assert s_qos.state_dim == qos.state_dim == 7
-    engines = [_engine(seed=0), _engine(seed=1)]
-    with pytest.raises(ValueError, match="state_dim"):
-        EdgeCluster(engines, s_base, qos_obs=True)
-    with pytest.raises(ValueError, match="state_dim"):
-        EdgeCluster(engines, s_qos, qos_obs=False)
-    # matching widths construct fine and auto-infer the QoS mode
-    assert EdgeCluster(engines, s_qos).qos_obs
-    assert not EdgeCluster(engines, s_base).qos_obs
+        assert s_base.num_engines == s_qos.num_engines == 2
+        for s, p in ((s_base, base), (s_qos, qos)):
+            cluster = EdgeCluster(engines, s)
+            assert cluster.obs_dim == p.state_dim
+            assert cluster.observe(_req(0)).shape == (p.state_dim,)
+    assert base.state_dim == 4 and qos.state_dim == 7
 
 
 # ---------------------------------------------------------------------------
@@ -349,7 +351,7 @@ def test_deadline_scheduler_drives_live_qos_cluster():
     mix = ((scaled(QoSClass("fast", priority=4.0, deadline_s=30.0),
                    z_range=(1, 2), model_pref="xlstm-350m"), 0.5),
            (QoSClass("slow", priority=1.0, z_range=(2, 4)), 0.5))
-    cluster = EdgeCluster(fleet, DeadlineAwareScheduler(2), qos_obs=True)
+    cluster = EdgeCluster(fleet, make_scheduler("deadline", 2))
     assert cluster.obs_dim == 3 + 2 * 2
     trace = poisson_trace(6, rate=50.0, prompt_len=8, max_new_tokens=4,
                           vocab_size=vocab, num_origins=2, seed=11,
