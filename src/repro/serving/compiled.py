@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.launch.sharding import param_shardings, state_shardings
 from repro.models.transformer import abstract_params, init_params
@@ -159,6 +160,41 @@ def paged_decode_step(cfg, num_pages: int, page_size: int, sample: bool,
         lambda: jax.jit(make_paged_decode_step(cfg, sample=sample,
                                                temperature=temperature),
                         donate_argnums=2))
+
+
+def first_token(sample: bool, mesh=None) -> Callable:
+    """Jitted pick of a prompt's first token from its last chunk's logits,
+    written into the engine's next-round token buffer.
+
+    signature: (toks (L, 1) [audio (L, K, 1)], chunk_logits (1, C, V...),
+    last, lane, key) -> (toks with row ``lane`` set, tok (1[, K])).  ``last``
+    and ``lane`` are traced scalars, so one compile covers every prompt
+    and lane.  The draw is the engine's eager one: argmax, or with
+    ``sample`` a categorical draw from ``key`` (untempered, as the
+    engine's own pick).  On a mesh both outputs are replicated, the
+    placement of the buffer the decode round reads."""
+    def build():
+        def pick(toks, logits, last, lane, key):
+            row = jax.lax.dynamic_index_in_dim(logits[0], last,
+                                               keepdims=True)
+            tok = (jax.random.categorical(key, row, axis=-1) if sample
+                   else jnp.argmax(row, axis=-1)).astype(jnp.int32)
+            return toks.at[lane].set(tok.reshape(toks.shape[1:])), tok
+
+        return jax.jit(pick, out_shardings=_replicated(mesh))
+
+    return _get(("first_token", sample, mesh), build)
+
+
+def token_column(mesh=None) -> Callable:
+    """Jitted (L[, K]) decode-round tokens -> (L[, K], 1), the layout the
+    next round reads, replicated on a mesh."""
+    return _get(("token_column", mesh), lambda: jax.jit(
+        lambda tok: tok[..., None], out_shardings=_replicated(mesh)))
+
+
+def _replicated(mesh):
+    return None if mesh is None else NamedSharding(mesh, PartitionSpec())
 
 
 def _fork(axis: int, src, dst) -> Callable:

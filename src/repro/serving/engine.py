@@ -109,8 +109,6 @@ class _Lane:
     table: BlockTable
     prompt_len: int
     chunk_pos: int = 0       # next prompt position to prefill
-    length: int = 0          # KV positions written so far
-    last_tok: Optional[np.ndarray] = None
 
     @property
     def decoding(self) -> bool:
@@ -211,6 +209,19 @@ class ServeEngine:
             self._pool = PagePool(num_pages, page_size)
             self._lanes: List[Optional[_Lane]] = [None] * self.max_lanes
             self._paged_states = None   # built lazily on first admission
+            # the decode round's inputs: host tables and lengths (a row
+            # is set when its lane starts decoding and cleared when it is
+            # freed, so prefilling and idle lanes ride masked: null table,
+            # length 0), placed once a round; and the round's tokens,
+            # kept on the device: the last round's output with each
+            # finished prompt's first token written into its lane's row
+            self._tables = np.zeros((self.max_lanes, self._row_width),
+                                    np.int32)
+            self._lengths = np.zeros((self.max_lanes,), np.int32)
+            self._toks = None           # built with the page pools
+            self._first_token = compiled.first_token(sample, mesh=mesh)
+            self._token_column = compiled.token_column(mesh=mesh)
+            self._key0 = jax.random.key(0)  # the greedy draw ignores it
             self._paged_prefill = compiled.paged_prefill_step(
                 cfg, num_pages, page_size, mesh=mesh)
             self._paged_decode = compiled.paged_decode_step(
@@ -354,9 +365,7 @@ class ServeEngine:
                 if fin:
                     req.finish(now)
                     finished.append(req)
-                elif self.paged:
-                    self._lanes[i].last_tok = tk
-                else:
+                elif not self.paged:
                     self._last_tok[i] = tk
         return finished
 
@@ -432,6 +441,7 @@ class ServeEngine:
         # only; last-chunk first-tokens sync in collect())
         self._ensure_paged_states()
         C = self.prefill_chunk
+        fresh = 0
         for i, lane in enumerate(self._lanes):
             if lane is None or lane.decoding:
                 continue
@@ -440,31 +450,36 @@ class ServeEngine:
             with tracing.span("repro.engine.prefill", rid=req.rid, lane=i,
                               start=c0,
                               tokens=min(C, lane.prompt_len - c0)):
-                self._prefill_chunk(pend, i, lane)
+                fresh += self._prefill_chunk(pend, i, lane)
 
         # one decode round across the lanes that finished prefilling;
         # idle/prefilling lanes ride along masked (null table, length 0)
         active = [i for i, ln in enumerate(self._lanes)
                   if ln is not None and ln.decoding]
         if active:
-            with tracing.span("repro.engine.decode_inputs"):
-                batch, context = self._decode_inputs(active)
-                key = self._next_key()
+            with tracing.span("repro.engine.decode_inputs") as sp:
+                # copies: the host rows change before the transfer ends
+                tables, lengths = jax.device_put(
+                    (self._tables.copy(), self._lengths.copy()))
+                context = (self._lengths[active] + 1).tolist()
+                key = self._next_key() if self.sample else self._key0
+                sp.set(fresh=fresh, placed=1)
             self._round_t0 = self._clock()
             with tracing.span("repro.engine.decode", lanes=len(active),
                               lengths=context), self._sharded():
                 _, tok_all, self._paged_states, *experts = \
-                    self._paged_decode(self.params, batch,
-                                       self._paged_states, key)
+                    self._paged_decode(
+                        self.params,
+                        {"tokens": self._toks, "block_tables": tables,
+                         "lengths": lengths},
+                        self._paged_states, key)
+                self._toks = self._token_column(tok_all)
+            self._lengths[active] += 1             # decode wrote one KV
             entries = []
             for i in active:
-                lane = self._lanes[i]
-                req = lane.req
-                tk = tok_all[i:i + 1]              # device slice, lazy
+                req = self._lanes[i].req
                 pos = len(req.tokens)
-                req.tokens.append(tk)
-                lane.last_tok = tk
-                lane.length += 1                   # decode wrote one KV
+                req.tokens.append(None)            # collect() fills it
                 fin = len(req.tokens) >= req.max_new_tokens
                 entries.append((i, req, pos, fin))
                 if fin:
@@ -534,14 +549,15 @@ class ServeEngine:
                 self.prefill_tokens_saved += matched
                 req.prefix_tokens = matched
             self._lanes[i] = _Lane(req=req, table=table, prompt_len=plen,
-                                   chunk_pos=matched, length=matched)
+                                   chunk_pos=matched)
             admitted += 1
         self._note_inflight(sum(ln is not None for ln in self._lanes))
         return admitted
 
-    def _prefill_chunk(self, pend: _Pending, i: int, lane: _Lane) -> None:
+    def _prefill_chunk(self, pend: _Pending, i: int, lane: _Lane) -> int:
         """Enqueue lane ``i``'s next prompt chunk; on the prompt's last
-        chunk, its first token (synced in collect())."""
+        chunk, its first token (synced in collect()), written into the
+        next round's token buffer.  Returns 1 if the prompt ended."""
         req, C = lane.req, self.prefill_chunk
         if req.t_prefill_start is None:        # first chunk (chunk_pos may
             req.t_prefill_start = self._clock()    # start past 0 on a hit)
@@ -560,46 +576,29 @@ class ServeEngine:
                  "block_table": row},
                 self._paged_states)
         lane.chunk_pos = c0 + C
-        lane.length = min(lane.chunk_pos, lane.prompt_len)
-        if lane.decoding:                      # last chunk of prompt
-            last = lane.prompt_len - 1 - c0
-            with self._sharded():
-                tok = self._pick(logits[0, last][None])
-            pos = len(req.tokens)
-            req.tokens.append(tok)
-            lane.last_tok = tok
-            fin = len(req.tokens) >= req.max_new_tokens
-            pend.prefill.append((req, tok, pos, fin))
-            # the prompt's KV is complete: index every full prompt
-            # block so later prompts with the same chain reuse it
-            # (insert BEFORE any lane release so cached pages carry
-            # their reference when the lane lets go)
-            if self._prefix is not None:
-                self._prefix.insert(req.prompt, lane.table.pages)
-            if fin:
-                self._free_lane(i)
-
-    def _decode_inputs(self, active: List[int]):
-        """The decode round's batch over all lanes (idle and prefilling
-        lanes masked: null table, length 0), and the context length, in
-        keys attended, of each active lane."""
-        L, W = self.max_lanes, self._row_width
-        rows = [self._zero_tok] * L
-        tables = np.zeros((L, W), np.int32)
-        lengths = np.zeros((L,), np.int32)
-        context = []
-        for i in active:
-            lane = self._lanes[i]
-            rows[i] = lane.last_tok            # may be a device array
-            tables[i] = lane.table.row(W)
-            lengths[i] = lane.length
-            context.append(lane.length + 1)    # with the token fed now
-        toks = jnp.stack([jnp.asarray(r, jnp.int32) for r in rows])
-        if self.cfg.num_codebooks:
-            toks = jnp.transpose(toks, (0, 2, 1))  # (L,1,K)->(L,K,1)
-        return ({"tokens": toks,
-                 "block_tables": jnp.asarray(tables, jnp.int32),
-                 "lengths": jnp.asarray(lengths, jnp.int32)}, context)
+        if not lane.decoding:
+            return 0
+        # last chunk of the prompt
+        key = self._next_key() if self.sample else self._key0
+        with self._sharded():
+            self._toks, tok = self._first_token(
+                self._toks, logits, lane.prompt_len - 1 - c0, i, key)
+        pos = len(req.tokens)
+        req.tokens.append(tok)
+        fin = len(req.tokens) >= req.max_new_tokens
+        pend.prefill.append((req, tok, pos, fin))
+        # the prompt's KV is complete: index every full prompt
+        # block so later prompts with the same chain reuse it
+        # (insert BEFORE any lane release so cached pages carry
+        # their reference when the lane lets go)
+        if self._prefix is not None:
+            self._prefix.insert(req.prompt, lane.table.pages)
+        if fin:
+            self._free_lane(i)
+        else:                                  # decodes from next round
+            self._tables[i] = lane.table.row(self._row_width)
+            self._lengths[i] = lane.prompt_len
+        return 1
 
     def run_to_completion(self, max_steps: int = 1_000_000) -> List[Request]:
         """Step until queue and slots drain; returns finished requests."""
@@ -643,6 +642,8 @@ class ServeEngine:
                 if lane is not None:
                     lane.table.release()
             self._lanes = [None] * self.max_lanes
+            self._tables[:] = 0
+            self._lengths[:] = 0
             if self._prefix is not None:
                 self._prefix.clear()
             if self._pool.num_free != self.num_pages - 1:
@@ -912,6 +913,8 @@ class ServeEngine:
     def _free_lane(self, i: int) -> None:
         self._lanes[i].table.release()
         self._lanes[i] = None
+        self._tables[i] = 0
+        self._lengths[i] = 0
 
     def _ensure_paged_states(self) -> None:
         if self._paged_states is None:
@@ -919,6 +922,11 @@ class ServeEngine:
             self._paged_states = self._zeros(init_paged_states(
                 self.cfg, self.num_pages, self.page_size,
                 make=jax.ShapeDtypeStruct))
+            # through the column op: the placement every later round's
+            # buffer has, so the first round compiles nothing of its own
+            K = self.cfg.num_codebooks
+            self._toks = self._token_column(jnp.zeros(
+                (self.max_lanes, K) if K else (self.max_lanes,), jnp.int32))
 
     def _ensure_pool(self, st):
         """Lazily build the slot pool from the structure of the first

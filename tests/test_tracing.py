@@ -181,6 +181,12 @@ def test_cluster_scheduler_and_engine_spans_follow_the_layers():
     for d in decode:
         assert len(d.attrs["lengths"]) == d.attrs["lanes"]
         assert set(d.attrs["lengths"]) <= {13, 14}
+    # each round places its host inputs once; each prompt's first token
+    # joins the device buffer in the dispatch of its first round
+    inputs = _by_name(spans, "repro.engine.decode_inputs")
+    assert len(inputs) == len(decode)
+    assert all(s.attrs["placed"] == 1 for s in inputs)
+    assert sum(s.attrs["fresh"] for s in inputs) == 3
     assert all(r.status == "ok" for r in reqs)
 
 
